@@ -51,6 +51,9 @@ from repro.obs.trace import NULL as _NULL_TRACER
 
 MiB = 1024 * 1024
 DEFAULT_STREAM_GRANULE = 1 * MiB
+# where IntegrityEngine digests: "host" (numpy GEMM) or "pallas" (every
+# read-back and deferred-source digest on the accelerator)
+INTEGRITY_BACKENDS = ("host", "pallas")
 
 
 # ---------------------------------------------------------------------------
@@ -243,25 +246,31 @@ def read_back_fingerprint(
     *,
     pool: "BufferPool | None" = None,
     granule: int = DEFAULT_STREAM_GRANULE,
+    fingerprint: Callable[[memoryview], Digest] | None = None,
 ) -> Digest:
     """Fingerprint the landed bytes, cheapest path first: in place via the
     destination's zero-copy ``read_back_view`` when it has one, else into a
     pooled buffer, else through the classic ``read_back()`` bytes. Shared by
-    the integrity engine and the single-pass inline verifier."""
+    the integrity engine and the single-pass inline verifier.
+    ``fingerprint`` replaces the host granule digest (the engine's device
+    backend passes its own)."""
+    if fingerprint is None:
+        def fingerprint(mv: memoryview) -> Digest:
+            return fingerprint_view(mv, granule)
     viewfn = getattr(dest, "read_back_view", None)
     if viewfn is not None:
         mv = viewfn(offset, length)
         try:
-            return fingerprint_view(mv, granule)
+            return fingerprint(mv)
         finally:
             if isinstance(mv, memoryview):
                 mv.release()
     if pool is not None:
         with pool.acquire(length) as buf:
             read_back_into(dest, offset, buf.view)
-            return fingerprint_view(buf.view, granule)
+            return fingerprint(buf.view)
     back = dest.read_back(offset, length)
-    return fingerprint_view(memoryview(back), granule)
+    return fingerprint(memoryview(back))
 
 
 def stream_chunk(
@@ -347,35 +356,6 @@ def stream_chunk(
     return rf.digest(), ck_s
 
 
-def _digest_rows_pallas(rows: list["np.ndarray"]) -> list[Digest]:
-    """Batched digests with the accelerator in the loop: equal-length groups
-    whose byte length tiles the checksum kernel grid go through ONE
-    ``checksum_many_words`` dispatch per group; everything else (ragged
-    leftovers, non-tile lengths) falls back to the host GEMM stack. Imports
-    lazily so host-only deployments never pay the jax import."""
-    from repro.kernels import checksum as _ck
-    import jax.numpy as jnp
-
-    out: list[Digest | None] = [None] * len(rows)
-    groups: dict[int, list[int]] = {}
-    for i, r in enumerate(rows):
-        groups.setdefault(int(r.size), []).append(i)
-    host_idx: list[int] = []
-    for n, idxs in groups.items():
-        if n > 0 and n % _ck.TILE_BYTES == 0:
-            mat = np.stack([rows[i] for i in idxs]).view(np.int32)
-            res = np.asarray(_ck.checksum_many_words(jnp.asarray(mat)))
-            for row_j, i in enumerate(idxs):
-                out[i] = Digest(tuple(int(v) for v in res[row_j]), n)
-        else:
-            host_idx.extend(idxs)
-    if host_idx:
-        digs = fingerprint_many([rows[i] for i in host_idx])
-        for i, d in zip(host_idx, digs):
-            out[i] = d
-    return out                                        # type: ignore[return-value]
-
-
 # ---------------------------------------------------------------------------
 # the decoupled integrity engine
 # ---------------------------------------------------------------------------
@@ -412,6 +392,8 @@ class IntegrityStats:
     cksum_seconds: float = 0.0   # read-back + fingerprint work time
     fused_batches: int = 0       # drain rounds digested as one fused dispatch
     fused_jobs: int = 0          # jobs that rode a fused dispatch
+    device_bytes: int = 0        # bytes digested on the accelerator
+    host_bytes: int = 0          # bytes digested on the host (0 under "pallas")
 
 
 class IntegrityEngine:
@@ -437,10 +419,13 @@ class IntegrityEngine:
     ``fingerprint_many`` dispatch (equal-length granules stack into a single
     GEMM; ragged lengths fall back per-item inside). Jobs larger than
     ``fuse_max_bytes`` keep the per-chunk granule-streaming path, which is
-    already bandwidth-bound at that size. ``backend="pallas"`` additionally
-    routes tile-aligned equal-length groups through the batched
-    ``kernels.checksum.checksum_many_words`` kernel (one accelerator dispatch
-    per drain batch); the host GEMM stack handles whatever does not tile.
+    already bandwidth-bound at that size.
+
+    ``backend="pallas"`` sends EVERY digest the engine takes to the device
+    through ``kernels.fingerprint_host_rows`` (bucketed ``checksum_many_words``
+    dispatches): fused drains, singleton drains, ragged lengths and oversize
+    jobs alike, so ``stats.host_bytes`` stays 0. ``"host"`` digests with the
+    numpy GEMM path.
     """
 
     _SENTINEL = None
@@ -464,7 +449,7 @@ class IntegrityEngine:
             raise ValueError("workers must be >= 1")
         if batch < 1:
             raise ValueError("batch must be >= 1")
-        if backend not in ("host", "pallas"):
+        if backend not in INTEGRITY_BACKENDS:
             raise ValueError(f"unknown integrity backend {backend!r}")
         self._pool = pool
         self._fuse = bool(fuse)
@@ -725,9 +710,27 @@ class IntegrityEngine:
                 self._on_error(job, e)
 
     def _digest_rows(self, rows: list[np.ndarray]) -> list[Digest]:
+        """The engine's one digest dispatch: device or host by backend, with
+        the digested bytes counted against the side that did the work."""
+        nbytes = sum(int(r.size) for r in rows)
         if self._backend == "pallas":
-            return _digest_rows_pallas(rows)
-        return fingerprint_many(rows)
+            from repro.kernels.ops import fingerprint_host_rows  # jax on demand
+            digs = fingerprint_host_rows(rows)
+            with self._lock:
+                self.stats.device_bytes += nbytes
+            return digs
+        digs = fingerprint_many(rows)
+        with self._lock:
+            self.stats.host_bytes += nbytes
+        return digs
+
+    def _fingerprint(self, mv: memoryview) -> Digest:
+        """Digest one whole job region (the per-job path)."""
+        if self._backend == "pallas":
+            return self._digest_rows([np.frombuffer(mv, dtype=np.uint8)])[0]
+        with self._lock:
+            self.stats.host_bytes += len(mv)
+        return fingerprint_view(mv)
 
     def _verify_one(self, job: VerifyJob, wid: int = 0) -> None:
         t0 = time.perf_counter()
@@ -743,7 +746,7 @@ class IntegrityEngine:
                 # from the source's stable view (same bytes the mover wrote)
                 src_mv = job.source.read_view(job.offset, job.length)
                 try:
-                    job.expected = fingerprint_view(src_mv)
+                    job.expected = self._fingerprint(src_mv)
                 finally:
                     if isinstance(src_mv, memoryview):
                         src_mv.release()
@@ -751,7 +754,8 @@ class IntegrityEngine:
             # the landed bytes in place (in-memory dests expose their image
             # as a view; concurrent movers only touch disjoint offsets)
             actual = read_back_fingerprint(
-                job.dest, job.offset, job.length, pool=self._pool)
+                job.dest, job.offset, job.length, pool=self._pool,
+                fingerprint=self._fingerprint)
         except BaseException as e:  # noqa: BLE001 — routed to the caller
             with self._lock:
                 self.stats.errors += 1

@@ -39,6 +39,7 @@ from repro.core.chunker import (
 )
 from repro.core.dataplane import (
     DEFAULT_STREAM_GRANULE,
+    INTEGRITY_BACKENDS,
     BufferPool,
     IntegrityEngine,
     VerifyJob,
@@ -410,6 +411,8 @@ class TransferReport:
     deduped_chunks: int = 0        # chunks satisfied from the chunk index
     dedup_bytes_saved: int = 0     # wire bytes those chunks would have cost
     dedup_demoted: int = 0         # stale/corrupt index hits demoted to wire
+    verify_device_bytes: int = 0   # integrity-engine bytes digested on device
+    verify_host_bytes: int = 0     # integrity-engine bytes digested on host
 
     @property
     def gbps(self) -> float:
@@ -438,6 +441,7 @@ class ChunkedTransfer:
         alignment: int = 1,                # re-plan cut-point alignment
         pipeline: str = "serial",          # serial | single_pass | pipelined
         integrity_workers: int = 2,        # checksum worker pool (pipelined)
+        integrity_backend: str = "host",   # host | pallas (pipelined only)
         stream_granule: int = DEFAULT_STREAM_GRANULE,
         pool: BufferPool | None = None,    # shared buffer pool (else per-run)
         tracer=None,                       # obs.Tracer: chunk-lifecycle spans
@@ -464,6 +468,14 @@ class ChunkedTransfer:
                 "speculated twin racing a deferred verify could journal a "
                 "chunk the verifier has not vouched for"
             )
+        if integrity_backend not in INTEGRITY_BACKENDS:
+            raise ValueError(f"integrity_backend must be one of "
+                             f"{INTEGRITY_BACKENDS}, got {integrity_backend!r}")
+        if integrity_backend != "host" and pipeline != "pipelined":
+            raise ValueError(
+                f"integrity_backend={integrity_backend!r} needs "
+                "pipeline='pipelined': only the integrity engine digests "
+                "off the host")
         if pipeline == "pipelined" and not integrity:
             pipeline = "single_pass"    # nothing to defer without read-back
         if integrity_workers < 1:
@@ -483,6 +495,7 @@ class ChunkedTransfer:
         self.integrity = integrity
         self.pipeline = pipeline
         self.integrity_workers = integrity_workers
+        self.integrity_backend = integrity_backend
         self.stream_granule = max(1, int(stream_granule))
         self.journal = journal
         self.max_retries = max_retries
@@ -1234,6 +1247,7 @@ class ChunkedTransfer:
                 on_verified=self._on_verified, on_corrupt=self._on_corrupt,
                 on_error=self._on_verify_error,
                 tracer=self.tracer, task=self.task,
+                backend=self.integrity_backend,
             )
         # warm start: a SimTuner-seeded controller may already disagree with
         # the static plan — re-cut the whole tail before the first byte moves
@@ -1306,6 +1320,7 @@ class ChunkedTransfer:
         parts += resumed_parts
         parts += self._deduped_parts
         file_digest = combine_at_offsets(parts, self.plan.total_bytes)
+        estats = self._engine.stats if self._engine is not None else None
         return TransferReport(
             total_bytes=self.plan.total_bytes,
             file_digest=file_digest,
@@ -1328,6 +1343,8 @@ class ChunkedTransfer:
             deduped_chunks=self._deduped_chunks,
             dedup_bytes_saved=self._dedup_bytes_saved,
             dedup_demoted=self._dedup_demoted,
+            verify_device_bytes=estats.device_bytes if estats else 0,
+            verify_host_bytes=estats.host_bytes if estats else 0,
         )
 
     def _speculate(self, q: "queue.Queue[Chunk]", movers: int, skip: set[int]) -> None:

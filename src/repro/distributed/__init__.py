@@ -9,8 +9,7 @@ from repro.distributed.chunked import (
 )
 from repro.distributed.fsdp import cross_pod_mean, manual_pod
 from repro.distributed.mesh import (
-    DATA, MODEL, POD, MeshPlan, axis_size, batch_spec, make_mesh, shard,
-    shard_map, spec,
+    DATA, MODEL, POD, MeshPlan, axis_size, batch_spec, shard, spec,
 )
 
 __all__ = [
@@ -18,5 +17,5 @@ __all__ = [
     "chunked_reduce_scatter", "default_n_chunks", "matmul_rs",
     "cross_pod_mean", "manual_pod",
     "DATA", "MODEL", "POD", "MeshPlan", "axis_size", "batch_spec",
-    "make_mesh", "shard", "shard_map", "spec",
+    "shard", "spec",
 ]

@@ -25,24 +25,6 @@ import jax
 import jax.numpy as jnp
 
 
-# Old-JAX shim hooks (see distributed.mesh.shard_map). In partially-manual
-# shard_map regions, jax<0.5's XLA partitioner cannot lower lax.axis_index
-# (PartitionId) and hard-crashes on ppermute/all_gather/psum_scatter (manual-
-# subgroup sharding checks); only psum survives. The compat shard_map
-# therefore (a) threads each manual axis's index in as a sharded operand,
-# registered in _AXIS_INDEX_OVERRIDE for the trace, and (b) lists the axes in
-# _PSUM_FALLBACK_AXES so the collectives below drop to psum-based equivalents
-# — numerically identical, bandwidth-suboptimal, and only ever taken on the
-# legacy-JAX partial-manual path.
-_AXIS_INDEX_OVERRIDE: dict[str, jax.Array] = {}
-_PSUM_FALLBACK_AXES: set[str] = set()
-
-
-def _axis_index(axis_name: str) -> jax.Array:
-    ov = _AXIS_INDEX_OVERRIDE.get(axis_name)
-    return ov if ov is not None else jax.lax.axis_index(axis_name)
-
-
 def _ring_perm(axis_size: int, reverse: bool = False):
     if reverse:
         return [((i + 1) % axis_size, i) for i in range(axis_size)]
@@ -68,14 +50,9 @@ def chunked_all_gather(
     jax.lax.all_gather(x, axis_name, tiled=True) (the monolithic baseline).
     """
     s = x.shape[0]
-    if axis_name in _PSUM_FALLBACK_AXES:
-        # legacy-JAX partial-manual region: place the shard, sum across axis
-        out = jnp.zeros((axis_size * s,) + x.shape[1:], x.dtype)
-        out = jax.lax.dynamic_update_slice_in_dim(out, x, _axis_index(axis_name) * s, axis=0)
-        return jax.lax.psum(out, axis_name)
     if n_chunks > 1 and s % n_chunks != 0:
         n_chunks = 1  # fall back rather than mis-chunk
-    idx = _axis_index(axis_name)
+    idx = jax.lax.axis_index(axis_name)
     perm = _ring_perm(axis_size)
 
     pieces = jnp.split(x, n_chunks, axis=0) if n_chunks > 1 else [x]
@@ -112,13 +89,9 @@ def chunked_reduce_scatter(
     rows = x.shape[0]
     assert rows % axis_size == 0, (rows, axis_size)
     s = rows // axis_size
-    if axis_name in _PSUM_FALLBACK_AXES:
-        # legacy-JAX partial-manual region: sum everything, keep our block
-        full = jax.lax.psum(x, axis_name)
-        return jax.lax.dynamic_slice_in_dim(full, _axis_index(axis_name) * s, s, axis=0)
     if n_chunks > 1 and s % n_chunks != 0:
         n_chunks = 1
-    idx = _axis_index(axis_name)
+    idx = jax.lax.axis_index(axis_name)
     perm = _ring_perm(axis_size)
     cs = s // n_chunks
 
@@ -148,8 +121,6 @@ def chunked_all_reduce(
     synchronization path: the cross-pod (DCN) hop is the slow WAN-like link
     where the paper's chunking pays most.
     """
-    if axis_name in _PSUM_FALLBACK_AXES:
-        return jax.lax.psum(x, axis_name)   # legacy-JAX partial-manual region
     shape = x.shape
     flat = x.reshape(-1)
     groups = axis_size * n_chunks
@@ -179,13 +150,7 @@ def ag_matmul(
     B, K = x.shape
     kA, N = w_shard.shape
     assert kA * axis_size == K, (x.shape, w_shard.shape, axis_size)
-    if axis_name in _PSUM_FALLBACK_AXES:
-        # legacy-JAX partial-manual region: gather W via psum, then one matmul
-        full = jnp.zeros((K, N), w_shard.dtype)
-        full = jax.lax.dynamic_update_slice_in_dim(
-            full, w_shard, _axis_index(axis_name) * kA, axis=0)
-        return x @ jax.lax.psum(full, axis_name)
-    idx = _axis_index(axis_name)
+    idx = jax.lax.axis_index(axis_name)
     perm = _ring_perm(axis_size, reverse=True)  # pull blocks from the right
 
     def x_block(owner: jax.Array) -> jax.Array:

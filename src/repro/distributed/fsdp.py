@@ -25,7 +25,7 @@ import jax
 from jax.sharding import Mesh
 
 from repro.distributed import chunked as C
-from repro.distributed.mesh import POD, axis_size, shard_map
+from repro.distributed.mesh import POD, axis_size
 
 
 def cross_pod_mean(tree: Any, n_pods: int, *, n_chunks: int = 4) -> Any:
@@ -53,7 +53,7 @@ def manual_pod(fn, mesh: Mesh, *, in_specs, out_specs):
     """
     if axis_size(mesh, POD) == 1:
         return fn
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
         axis_names={POD}, check_vma=False,
     )

@@ -12,104 +12,12 @@ axes; a model never hardcodes a mesh axis.
 from __future__ import annotations
 
 import dataclasses
-import math
 
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 POD, DATA, MODEL = "pod", "data", "model"
 
-
-def make_mesh(axis_shapes, axis_names, *, devices=None):
-    """Version-portable mesh construction (the jax.sharding.AxisType shim).
-
-    Newer JAX exposes ``jax.sharding.AxisType`` and ``jax.make_mesh(...,
-    axis_types=...)``; older releases (e.g. 0.4.x) have neither, and some
-    mid versions have ``make_mesh`` without the kwarg. All call sites build
-    Auto-typed meshes, so this helper requests AxisType.Auto when the
-    installed JAX understands it and silently degrades otherwise (Auto is
-    the implicit behaviour of the older APIs).
-    """
-    kw = {} if devices is None else {"devices": devices}
-    axis_type_cls = getattr(jax.sharding, "AxisType", None)
-    if axis_type_cls is not None:
-        try:
-            return jax.make_mesh(
-                tuple(axis_shapes), tuple(axis_names),
-                axis_types=(axis_type_cls.Auto,) * len(tuple(axis_names)), **kw,
-            )
-        except TypeError:      # make_mesh predates the axis_types kwarg
-            pass
-    if hasattr(jax, "make_mesh"):
-        return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kw)
-    import numpy as np         # oldest fallback: raw Mesh over a device grid
-
-    devs = list(devices) if devices is not None else jax.devices()[: math.prod(axis_shapes)]
-    return Mesh(np.asarray(devs).reshape(tuple(axis_shapes)), tuple(axis_names))
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None, check_vma=False):
-    """Version-portable shard_map (the jax.shard_map / check_vma shim).
-
-    New JAX: ``jax.shard_map(f, mesh=..., axis_names={manual axes},
-    check_vma=...)``. Older JAX only has ``jax.experimental.shard_map`` whose
-    knobs are inverted: ``auto`` lists the axes that STAY automatic
-    (complement of axis_names) and replication checking is ``check_rep``.
-    """
-    if hasattr(jax, "shard_map"):
-        kw = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check_vma)
-        if axis_names is not None:
-            kw["axis_names"] = set(axis_names)
-        return jax.shard_map(f, **kw)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    auto = frozenset()
-    if axis_names is not None:
-        auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-    if not auto:
-        return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                          check_rep=check_vma, auto=auto)
-
-    # Partial-manual region on old JAX: lax.axis_index lowers to an XLA
-    # PartitionId op that the old SPMD partitioner rejects inside
-    # partially-manual computations. Thread each manual axis's index in as a
-    # sharded iota operand instead and register it with the chunked-collective
-    # shim (repro.distributed.chunked._axis_index) for the trace.
-    from repro.distributed import chunked as _chunked
-    import jax.numpy as jnp
-
-    manual = [a for a in mesh.axis_names if a in frozenset(axis_names)]
-
-    def wrapped(idx_ops, *args):
-        for a, ix in zip(manual, idx_ops):
-            _chunked._AXIS_INDEX_OVERRIDE[a] = ix[0]
-        _chunked._PSUM_FALLBACK_AXES.update(manual)
-        try:
-            return f(*args)
-        finally:
-            for a in manual:
-                _chunked._AXIS_INDEX_OVERRIDE.pop(a, None)
-                _chunked._PSUM_FALLBACK_AXES.discard(a)
-
-    def outer(*args):
-        # one spec (pytree) per argument; note PartitionSpec is a tuple
-        # subclass, so a bare P(...) means "one arg", not a tuple of specs
-        if isinstance(in_specs, tuple) and not isinstance(in_specs, P) \
-                and len(in_specs) == len(args):
-            specs = in_specs
-        else:
-            specs = (in_specs,) * len(args)
-        inner = _shard_map(
-            wrapped, mesh=mesh,
-            in_specs=(tuple(P(a) for a in manual),) + specs,
-            out_specs=out_specs, check_rep=check_vma, auto=auto,
-        )
-        idx_ops = tuple(
-            jnp.arange(mesh.shape[a], dtype=jnp.int32) for a in manual
-        )
-        return inner(idx_ops, *args)
-
-    return outer
 
 # logical dim -> mesh axis (None = replicate)
 _RULES: dict[str, str | None] = {

@@ -1,4 +1,4 @@
-"""Pallas TPU kernels (validated in interpret mode on CPU).
+"""Pallas TPU kernels: compiled on TPU, run in interpret mode on CPU (tests).
 
   checksum.py      — chunk fingerprint kernel + single-pass checksum-copy
   matmul_digest.py — fused matmul + operand digest (consume-and-verify)
@@ -9,7 +9,9 @@ from repro.kernels.ops import (
     digest_of,
     fingerprint_and_copy,
     fingerprint_array,
+    fingerprint_host_rows,
     matmul_with_digest,
 )
 
-__all__ = ["digest_of", "fingerprint_and_copy", "fingerprint_array", "matmul_with_digest"]
+__all__ = ["digest_of", "fingerprint_and_copy", "fingerprint_array",
+           "fingerprint_host_rows", "matmul_with_digest"]

@@ -13,6 +13,11 @@ Kernels:
     the same HBM pass (the paper's "checksum while first reading the file",
     Fig. 4 caption) — one read instead of two.
 
+Execution: ``interpret=None`` (the default everywhere) resolves from the
+platform JAX runs on — interpreted on ``cpu`` (how the tests validate the
+kernel bodies), compiled on ``tpu``; any other platform is an error, never a
+silent interpreter run.
+
 Tiling: the grid walks (ROWS, 128)-word tiles; TPU grids execute sequentially
 on a core, so the running digest accumulates in the output ref across steps
 (init at step 0). Per-tile weight tables live in VMEM and are reused every
@@ -45,6 +50,22 @@ TILE_BYTES = 4 * TILE_WORDS
 
 def _pow_mod(base: int, exp: int) -> int:
     return pow(int(base), int(exp), P)
+
+
+def resolve_interpret(interpret: bool | None) -> bool:
+    """Interpret on ``cpu``, compile on ``tpu``; an explicit bool wins.
+
+    Any other platform raises: a digest kernel must never fall back to the
+    interpreter (or to a reference path) on an accelerator.
+    """
+    if interpret is not None:
+        return bool(interpret)
+    platform = jax.default_backend()
+    if platform == "cpu":
+        return True
+    if platform == "tpu":
+        return False
+    raise RuntimeError(f"no Pallas digest kernel for platform {platform!r}")
 
 
 @functools.lru_cache(maxsize=None)
@@ -109,12 +130,14 @@ def _common_specs(rows: int):
     ]
 
 
-def checksum_words(words: jax.Array, *, rows: int = ROWS, interpret: bool = True) -> jax.Array:
+def checksum_words(
+    words: jax.Array, *, rows: int = ROWS, interpret: bool | None = None
+) -> jax.Array:
     """Digest residues (NBASES,) int32 of an int32 word stream.
 
     ``words`` must be 1-D int32 with size % (rows*128) == 0 (the ops.py wrapper
-    handles padding + pad correction). ``interpret=True`` runs the kernel body
-    on CPU — this container's validation mode; on TPU pass False.
+    handles padding + pad correction). ``interpret`` follows
+    ``resolve_interpret``: the interpreter on CPU, the compiled kernel on TPU.
     """
     assert words.ndim == 1 and words.dtype == jnp.int32, (words.shape, words.dtype)
     tile = rows * LANES
@@ -127,7 +150,7 @@ def checksum_words(words: jax.Array, *, rows: int = ROWS, interpret: bool = True
         in_specs=_common_specs(rows),
         out_specs=pl.BlockSpec((1, NBASES), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((1, NBASES), jnp.int32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
         name="chunk_checksum",
     )(words.reshape(-1, LANES), jnp.asarray(w0), jnp.asarray(rinv), jnp.asarray(rpow))
     return out[0]
@@ -148,7 +171,7 @@ def _checksum_many_kernel(words_ref, w0_ref, rinv_ref, rpow_ref, out_ref):
 
 
 def checksum_many_words(
-    words2d: jax.Array, *, rows: int = ROWS, interpret: bool = True
+    words2d: jax.Array, *, rows: int = ROWS, interpret: bool | None = None
 ) -> jax.Array:
     """Digests of k equal-length int32 word streams in ONE kernel dispatch.
 
@@ -161,6 +184,11 @@ def checksum_many_words(
     chunk — the same per-call amortization ``fingerprint_rows`` does for the
     host GEMM path, with the weight tables pinned in VMEM across the whole
     batch. Returns (k, NBASES) int32 residues.
+
+    The output is laid out (k, 1, NBASES) with one (1, NBASES) block per
+    stream: the TPU compiler only accepts blocks whose last two dims equal
+    the array's (or divide by 8 and 128), which a (1, NBASES) block of a
+    (k, NBASES) array does not.
     """
     assert words2d.ndim == 2 and words2d.dtype == jnp.int32, (words2d.shape, words2d.dtype)
     k, n = words2d.shape
@@ -177,16 +205,16 @@ def checksum_many_words(
             pl.BlockSpec((NBASES, 4), lambda i, j: (0, 0)),             # r^-k scalars
             pl.BlockSpec((NBASES, 1), lambda i, j: (0, 0)),             # r^T scalar
         ],
-        out_specs=pl.BlockSpec((1, NBASES), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((k, NBASES), jnp.int32),
-        interpret=interpret,
+        out_specs=pl.BlockSpec((None, 1, NBASES), lambda i, j: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((k, 1, NBASES), jnp.int32),
+        interpret=resolve_interpret(interpret),
         name="chunk_checksum_many",
     )(words2d.reshape(k, -1, LANES), jnp.asarray(w0), jnp.asarray(rinv), jnp.asarray(rpow))
-    return out
+    return out[:, 0, :]
 
 
 def checksum_copy_words(
-    words: jax.Array, *, rows: int = ROWS, interpret: bool = True
+    words: jax.Array, *, rows: int = ROWS, interpret: bool | None = None
 ) -> tuple[jax.Array, jax.Array]:
     """Copy an int32 word stream while digesting it (one pass over HBM).
 
@@ -212,7 +240,7 @@ def checksum_copy_words(
             jax.ShapeDtypeStruct((1, NBASES), jnp.int32),
             jax.ShapeDtypeStruct((words.size // LANES, LANES), jnp.int32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
         name="chunk_checksum_copy",
     )(words.reshape(-1, LANES), jnp.asarray(w0), jnp.asarray(rinv), jnp.asarray(rpow))
     return digest[0], copy.reshape(-1)

@@ -24,6 +24,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.integrity import BASES, NBASES, P
+from repro.kernels.checksum import resolve_interpret
 
 LANES = 128
 
@@ -103,7 +104,7 @@ def matmul_digest(
     bm: int = 128,
     bn: int = 128,
     bk: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """C = A @ B (f32 accumulate) plus digest residues of A's blocked bytes.
 
@@ -136,7 +137,7 @@ def matmul_digest(
             jax.ShapeDtypeStruct((1, NBASES), jnp.int32),
         ],
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
         name="matmul_digest",
     )(a, b, jnp.asarray(w16), jnp.asarray(rinv1), jnp.asarray(rpow))
     return out, dig[0]

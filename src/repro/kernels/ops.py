@@ -5,6 +5,8 @@ Entry points:
   fingerprint_and_copy(x)     -> (residues, copy) — single-pass mover kernel
   digest_of(x)                -> core.integrity.Digest (host convenience)
   matmul_with_digest(a, b)    -> (a @ b, residues of a) — fused consume+verify
+  fingerprint_host_rows(rows) -> Digests of host byte rows, digested on device
+                                 (the integrity engine's device backend)
 
 Packing: any array is flattened and bitcast to little-endian int32 words
 (verified identical to numpy ``.view``). Byte counts not divisible by 4 or by
@@ -13,16 +15,20 @@ inverse of r^pad (GF(p) is a field), so the returned residues equal the digest
 of the *true* byte stream — host `fingerprint_bytes` agrees bit-for-bit, which
 is exactly what lets device-side chunk digests be verified against host-side
 file digests in the checkpoint path.
+
+``interpret=None`` everywhere resolves per platform
+(``checksum.resolve_interpret``): interpreted on CPU, compiled on TPU.
 """
 from __future__ import annotations
 
 import functools
+from typing import Sequence
 
 import numpy as np
 import jax
 import jax.numpy as jnp
 
-from repro.core.integrity import BASES, NBASES, P, Digest
+from repro.core.integrity import BASES, EMPTY_DIGEST, NBASES, P, Digest
 from repro.kernels import checksum as _ck
 from repro.kernels import matmul_digest as _mm
 
@@ -32,24 +38,31 @@ def _pow_mod(base: int, exp: int) -> int:
 
 
 def _to_words(x: jax.Array) -> tuple[jax.Array, int]:
-    """Flatten + bitcast to int32 words (little-endian), zero-padding to 4B."""
+    """Flatten + pack into int32 words (little-endian), zero-padding the tail.
+
+    Sub-word dtypes are packed from a lane-dense (rows, per*128) view: the
+    j-th element of every word is every per-th lane, shifted into place. A
+    bitcast from an (n, per) view would be the same bytes, but the TPU tiles
+    the last two dims by (8, 128), so that minor dim of 2 or 4 pads each
+    element out to a whole tile row (a 0.4 GB bf16 leaf asks for 55 GB).
+    """
     flat = x.reshape(-1)
     isz = flat.dtype.itemsize
     nbytes = flat.size * isz
     if isz == 4:
-        words = jax.lax.bitcast_convert_type(flat, jnp.int32)
-    elif isz == 2:
-        if flat.size % 2:
-            flat = jnp.pad(flat, (0, 1))
-        words = jax.lax.bitcast_convert_type(flat.reshape(-1, 2), jnp.int32)
-    elif isz == 1:
-        pad = (-flat.size) % 4
-        if pad:
-            flat = jnp.pad(flat, (0, pad))
-        words = jax.lax.bitcast_convert_type(flat.reshape(-1, 4), jnp.int32)
-    else:
+        return jax.lax.bitcast_convert_type(flat, jnp.int32), nbytes
+    if isz not in (1, 2):
         raise NotImplementedError(f"unsupported itemsize {isz} for {flat.dtype}")
-    return words.reshape(-1), nbytes
+    per = 4 // isz                                   # elements per word
+    codes = jax.lax.bitcast_convert_type(flat, {1: jnp.uint8, 2: jnp.uint16}[isz])
+    pad = (-codes.size) % (per * _ck.LANES)
+    if pad:
+        codes = jnp.pad(codes, (0, pad))
+    lanes = codes.reshape(-1, per * _ck.LANES).astype(jnp.uint32)
+    words = lanes[:, 0::per]
+    for j in range(1, per):
+        words = words | (lanes[:, j::per] << (8 * isz * j))
+    return jax.lax.bitcast_convert_type(words, jnp.int32).reshape(-1), nbytes
 
 
 def _unpad_residues(res: jax.Array, padded_bytes: int, true_bytes: int) -> jax.Array:
@@ -64,7 +77,9 @@ def _unpad_residues(res: jax.Array, padded_bytes: int, true_bytes: int) -> jax.A
 
 
 @functools.partial(jax.jit, static_argnames=("rows", "interpret"))
-def fingerprint_array(x: jax.Array, *, rows: int = _ck.ROWS, interpret: bool = True) -> jax.Array:
+def fingerprint_array(
+    x: jax.Array, *, rows: int = _ck.ROWS, interpret: bool | None = None
+) -> jax.Array:
     """Digest residues (NBASES,) int32 of an array's little-endian byte image."""
     words, nbytes = _to_words(x)
     tile = rows * _ck.LANES
@@ -79,7 +94,7 @@ def fingerprint_array(x: jax.Array, *, rows: int = _ck.ROWS, interpret: bool = T
 
 @functools.partial(jax.jit, static_argnames=("rows", "interpret"))
 def fingerprint_and_copy(
-    x: jax.Array, *, rows: int = _ck.ROWS, interpret: bool = True
+    x: jax.Array, *, rows: int = _ck.ROWS, interpret: bool | None = None
 ) -> tuple[jax.Array, jax.Array]:
     """Single-HBM-pass mover: returns (residues, copy-of-x)."""
     words, nbytes = _to_words(x)
@@ -100,7 +115,7 @@ def fingerprint_and_copy(
     return res, copy.reshape(x.shape)
 
 
-def digest_of(x: jax.Array, *, interpret: bool = True) -> Digest:
+def digest_of(x: jax.Array, *, interpret: bool | None = None) -> Digest:
     """Host-side Digest of a device array (residues via the Pallas kernel)."""
     res = np.asarray(fingerprint_array(x, interpret=interpret))
     nbytes = x.size * x.dtype.itemsize
@@ -110,7 +125,75 @@ def digest_of(x: jax.Array, *, interpret: bool = True) -> Digest:
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
 def matmul_with_digest(
     a: jax.Array, b: jax.Array, *, bm: int = 128, bn: int = 128, bk: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Fused C = A @ B and digest of A (blocked order — see ref.blocked_view)."""
     return _mm.matmul_digest(a, b, bm=bm, bn=bn, bk=bk, interpret=interpret)
+
+
+# ---------------------------------------------------------------------------
+# host rows -> bucketed device dispatches (the served integrity path)
+# ---------------------------------------------------------------------------
+PIECE_BYTES = 8 * 1024 * 1024    # largest bucket; longer rows go as pieces
+BATCH_BYTES = 32 * 1024 * 1024   # staged bytes per dispatch
+BATCH_ROWS = 32                  # rows per dispatch for buckets <= 1 MiB
+# every padded row length the device is handed: TILE_BYTES * 2^i
+BUCKETS = tuple(_ck.TILE_BYTES << i
+                for i in range((PIECE_BYTES // _ck.TILE_BYTES).bit_length()))
+
+_checksum_many = jax.jit(_ck.checksum_many_words, static_argnames=("rows", "interpret"))
+
+
+def bucket_of(nbytes: int) -> int:
+    """Smallest bucket holding ``nbytes`` (1 <= nbytes <= PIECE_BYTES)."""
+    return next(b for b in BUCKETS if b >= nbytes)
+
+
+def batch_rows(bucket: int) -> int:
+    """The fixed row count k of every dispatch at ``bucket``."""
+    return max(1, min(BATCH_ROWS, BATCH_BYTES // bucket))
+
+
+@functools.lru_cache(maxsize=1 << 12)
+def _unpad_vector(pad: int) -> np.ndarray:
+    """r^-pad per base: divides ``pad`` trailing zero bytes back out."""
+    vec = np.asarray([_pow_mod(_pow_mod(r, pad), P - 2) for r in BASES], np.int64)
+    vec.flags.writeable = False                   # shared by every caller
+    return vec
+
+
+def fingerprint_host_rows(rows: Sequence[np.ndarray]) -> list[Digest]:
+    """Digests of 1-D uint8 host rows, every byte digested on the device.
+
+    Rows are cut into pieces of at most ``PIECE_BYTES``. Each piece is
+    zero-padded on the host to its bucket (a power-of-two number of kernel
+    tiles) and handed over as int32 words, ``batch_rows(bucket)`` pieces per
+    ``checksum_many_words`` dispatch; unused rows stay zero and are dropped.
+    The device therefore sees at most ``len(BUCKETS)`` shapes, however
+    ragged the input. The padding is divided back out exactly (the
+    ``_unpad_residues`` identity) and a row's pieces merge by the merge law.
+    """
+    todo: dict[int, list[tuple[int, int, int]]] = {}   # bucket -> (row, start, n)
+    for i, r in enumerate(rows):
+        for s in range(0, r.size, PIECE_BYTES):
+            n = min(PIECE_BYTES, r.size - s)
+            todo.setdefault(bucket_of(n), []).append((i, s, n))
+    parts: dict[tuple[int, int], Digest] = {}
+    for bucket, pieces in todo.items():
+        k = batch_rows(bucket)
+        for b0 in range(0, len(pieces), k):
+            batch = pieces[b0:b0 + k]
+            stage = np.zeros((k, bucket), np.uint8)
+            for j, (i, s, n) in enumerate(batch):
+                stage[j, :n] = rows[i][s:s + n]
+            res = np.asarray(_checksum_many(jnp.asarray(stage.view(np.int32))))
+            for j, (i, s, n) in enumerate(batch):
+                h = res[j].astype(np.int64) * _unpad_vector(bucket - n) % P
+                parts[(i, s)] = Digest(tuple(int(v) for v in h), n)
+    out = []
+    for i, r in enumerate(rows):
+        d = EMPTY_DIGEST
+        for s in range(0, r.size, PIECE_BYTES):
+            d = d.merge(parts[(i, s)])
+        out.append(d)
+    return out

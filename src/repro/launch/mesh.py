@@ -9,8 +9,7 @@ from __future__ import annotations
 import math
 
 import jax
-
-from repro.distributed.mesh import make_mesh
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -24,13 +23,4 @@ def make_production_mesh(*, multi_pod: bool = False):
             "the dry-run must set XLA_FLAGS=--xla_force_host_platform_device_count=512 "
             "before any jax import"
         )
-    return make_mesh(shape, axes, devices=devices)
-
-
-def make_host_mesh(shape=(2, 2), axes=("data", "model")):
-    """Small mesh over however many (fake) devices exist — tests/examples."""
-    n = math.prod(shape)
-    devices = jax.devices()[:n]
-    if len(devices) < n:
-        raise RuntimeError(f"need {n} devices, have {len(devices)}")
-    return make_mesh(shape, axes, devices=devices)
+    return jax.make_mesh(shape, axes, (AxisType.Auto,) * len(axes), devices=devices)

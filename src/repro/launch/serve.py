@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.registry import build_model
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.train import parse_mesh
 
 
@@ -64,4 +65,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

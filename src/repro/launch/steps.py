@@ -25,7 +25,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.registry import SHAPES, ShapeCell, build_model
 from repro.distributed.fsdp import cross_pod_mean
-from repro.distributed.mesh import DATA, MODEL, POD, axis_size, shard_map
+from repro.distributed.mesh import DATA, MODEL, POD, axis_size
 from repro.models import common as cm
 from repro.optim import adamw
 
@@ -87,14 +87,6 @@ def build_train_step(
     cell = cell or SHAPES["train_4k"]
     n_pods = axis_size(mesh, POD)
     chunked = sync_mode in ("chunked", "chunked_bf16") and n_pods > 1
-    # Legacy-JAX degradation: a whole train step inside a partially-manual
-    # shard_map (manual over pod, GSPMD over data/model) hard-crashes the old
-    # XLA partitioner (manual-subgroup sharding checks). Without jax.shard_map
-    # fall back to the auto path — GSPMD emits the monolithic cross-pod
-    # all-reduces; numerics are identical, only the explicit chunked schedule
-    # is lost (see tests/test_chunked_collectives.py::CHUNKED_STEP).
-    if chunked and not hasattr(jax, "shard_map"):
-        chunked = False
     compress = sync_mode == "chunked_bf16"
     model.pod_manual = chunked
 
@@ -150,7 +142,7 @@ def build_train_step(
         pod_batch = {k: P(POD, *([None] * (len(v.shape) - 1)))
                      for k, v in b_shapes.items()}
         scalar = P()
-        step = shard_map(
+        step = jax.shard_map(
             step_core, mesh=mesh,
             in_specs=(rep(pspecs), rep(ospecs), pod_batch),
             out_specs=(rep(pspecs), rep(ospecs),

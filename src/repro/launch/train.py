@@ -28,12 +28,12 @@ import time
 import numpy as np
 import jax
 import jax.numpy as jnp
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 from repro.ckpt import CheckpointManager
-from repro.configs.registry import SHAPES, ShapeCell, build_model
+from repro.configs.registry import ShapeCell, build_model
 from repro.data.pipeline import DataConfig, TokenPipeline
-from repro.distributed.mesh import make_mesh
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.steps import build_train_step
 from repro.optim import adamw
 
@@ -49,7 +49,32 @@ def parse_mesh(spec: str):
     devices = jax.devices()[: int(np.prod(dims))]
     if len(devices) < int(np.prod(dims)):
         raise RuntimeError(f"mesh {spec} needs {np.prod(dims)} devices, have {len(devices)}")
-    return make_mesh(tuple(dims), names, devices=devices)
+    return jax.make_mesh(tuple(dims), names, (AxisType.Auto,) * len(names),
+                         devices=devices)
+
+
+def build_training(arch: str, mesh, *, smoke: bool, seq_len: int,
+                   global_batch: int, lr: float, microbatches: int = 1,
+                   sync_mode: str = "auto"):
+    """(model, optimizer config, jitted train step) for ``arch`` on ``mesh``."""
+    model = build_model(arch, mesh, smoke=smoke)
+    cell = ShapeCell("custom", seq_len, global_batch, "train")
+    ocfg = adamw.AdamWConfig(lr=lr, warmup_steps=10)
+    bundle = build_train_step(model, mesh, ocfg, cell=cell,
+                              microbatches=microbatches, sync_mode=sync_mode)
+    step_fn = jax.jit(bundle.fn, in_shardings=bundle.in_shardings,
+                      out_shardings=bundle.out_shardings)
+    return model, ocfg, step_fn
+
+
+def init_state(mesh, model, ocfg, seed: int):
+    """Fresh (params, AdamW state), created sharded on ``mesh``."""
+    pspecs = model.param_specs(mesh)
+    params = jax.jit(
+        lambda: model.init_params(seed),
+        out_shardings=jax.tree.map(lambda s: NamedSharding(mesh, s), pspecs),
+    )()
+    return params, adamw.init(params, ocfg)
 
 
 def restore_into(mesh, model, ocfg, mgr: CheckpointManager):
@@ -84,29 +109,19 @@ def main(argv=None) -> dict:
     args = ap.parse_args(argv)
 
     mesh = parse_mesh(args.mesh)
-    model = build_model(args.arch, mesh, smoke=args.smoke)
+    model, ocfg, step_fn = build_training(
+        args.arch, mesh, smoke=args.smoke, seq_len=args.seq_len,
+        global_batch=args.global_batch, lr=args.lr,
+        microbatches=args.microbatches, sync_mode=args.sync_mode)
     cfg = model.cfg
-    cell = ShapeCell("custom", args.seq_len, args.global_batch, "train")
-    ocfg = adamw.AdamWConfig(lr=args.lr, warmup_steps=10)
-    bundle = build_train_step(model, mesh, ocfg, cell=cell,
-                              microbatches=args.microbatches,
-                              sync_mode=args.sync_mode)
     with mesh:
-        step_fn = jax.jit(bundle.fn, in_shardings=bundle.in_shardings,
-                          out_shardings=bundle.out_shardings)
-
         mgr = CheckpointManager(args.ckpt_dir) if args.ckpt_dir else None
         start = 0
         if mgr is not None and mgr.latest_step() is not None:
             params, opt, start = restore_into(mesh, model, ocfg, mgr)
             print(f"[restore] resumed from step {start} ({mgr.root})")
         else:
-            pspecs = model.param_specs(mesh)
-            params = jax.jit(
-                lambda: model.init_params(args.seed),
-                out_shardings=jax.tree.map(lambda s: NamedSharding(mesh, s), pspecs),
-            )()
-            opt = adamw.init(params, ocfg)
+            params, opt = init_state(mesh, model, ocfg, args.seed)
 
         data = TokenPipeline(
             DataConfig(vocab=cfg.vocab, seq_len=args.seq_len,
@@ -142,5 +157,6 @@ def main(argv=None) -> dict:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     out = main()
     print(f"final loss: {out['final_loss']:.4f}")

@@ -39,7 +39,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from repro.models import common as cm
 from repro.models.common import ModelConfig
 from repro.models.transformer import DenseLM
-from repro.distributed.mesh import MODEL, POD, DATA, shard_map
+from repro.distributed.mesh import MODEL, POD, DATA
 
 
 def expert_layout(cfg: ModelConfig, tp: int) -> tuple[int, int, int]:
@@ -208,7 +208,7 @@ class MoELM(DenseLM):
             if self.pod_manual:
                 manual.discard(POD)   # already manual in the enclosing region
             seq_ax = MODEL if seq_sharded else None
-            y = shard_map(
+            y = jax.shard_map(
                 block, mesh=self.mesh,
                 in_specs=(P(b_axes, seq_ax, None), P(None, None),
                           P(MODEL, None, None, None), P(MODEL, None, None, None),

@@ -56,6 +56,7 @@ from repro.core.integrity import (
 )
 from repro.core.dataplane import (
     DEFAULT_STREAM_GRANULE,
+    INTEGRITY_BACKENDS,
     BufferPool,
     IntegrityEngine,
     VerifyJob,
@@ -146,6 +147,7 @@ class ServiceConfig:
     # ---- data plane (zero-copy pipelined movement + integrity) -----------
     pipeline: str = "serial"         # serial | single_pass | pipelined
     integrity_workers: int = 2       # per-task checksum workers (pipelined)
+    integrity_backend: str = "host"  # host | pallas: where the engine digests
     stream_granule: int = DEFAULT_STREAM_GRANULE
     # ---- intra-chunk striping (concurrent sub-streams per large chunk) ---
     stripes: int = 1                 # stripe count per eligible chunk
@@ -173,6 +175,15 @@ class ServiceConfig:
             )
         if self.integrity_workers < 1:
             raise ValueError("integrity_workers must be >= 1")
+        if self.integrity_backend not in INTEGRITY_BACKENDS:
+            raise ValueError(
+                f"integrity_backend must be one of {INTEGRITY_BACKENDS}, "
+                f"got {self.integrity_backend!r}")
+        if self.integrity_backend != "host" and self.pipeline != "pipelined":
+            raise ValueError(
+                f"integrity_backend={self.integrity_backend!r} needs "
+                "pipeline='pipelined': only the integrity engine digests "
+                "off the host")
         if self.stripes < 1:
             raise ValueError(f"stripes must be >= 1, got {self.stripes}")
         if self.stripe_min_bytes < 1:
@@ -1135,6 +1146,7 @@ class TransferService:
                         t, work, job),
                     on_error=lambda job, exc: self._verify_error(t, job, exc),
                     tracer=self.tracer, task=task_id,
+                    backend=self.config.integrity_backend,
                 )
 
             reason = self._drive_workers(t, work, journal, jlock, n_work)
@@ -2012,5 +2024,7 @@ class TransferService:
                 pipeline=self.config.pipeline,
                 cksum_seconds=round(t.cksum_s, 6),
                 cksum_lag_s=round(t.cksum_lag_s, 6),
+                verify_device_bytes=t.engine.stats.device_bytes if t.engine else 0,
+                verify_host_bytes=t.engine.stats.host_bytes if t.engine else 0,
                 metrics=metrics_view,
             )

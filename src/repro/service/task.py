@@ -242,6 +242,8 @@ class TaskStatus:
     cksum_seconds: float = 0.0   # checksum work on the mover path (cumulative)
     cksum_lag_s: float = 0.0     # deferred-verification lag (cumulative; the
     #                              distance integrity ran behind movement)
+    verify_device_bytes: int = 0 # integrity-engine bytes digested on device
+    verify_host_bytes: int = 0   # integrity-engine bytes digested on host
     # observability view: per-task numbers pulled from the obs metrics
     # registry at snapshot time (wire-time quantiles, verify lag, retry
     # counts by class) — what ``transferd top`` renders per row

@@ -7,11 +7,11 @@ EQUIV = """
 import numpy as np, jax, jax.numpy as jnp, functools
 from jax.sharding import PartitionSpec as P
 from repro.distributed import chunked as C
-from repro.distributed.mesh import make_mesh, shard_map
+from jax.sharding import AxisType
 
-mesh = make_mesh((8,), ("x",))
+mesh = jax.make_mesh((8,), ("x",), (AxisType.Auto,))
 A = 8
-sm = functools.partial(shard_map, mesh=mesh, check_vma=False)
+sm = functools.partial(jax.shard_map, mesh=mesh, check_vma=False)
 rng = np.random.default_rng(3)
 
 x = jnp.asarray(rng.standard_normal((64, 16)).astype(np.float32))
@@ -57,13 +57,13 @@ CROSS_POD = """
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 from repro.distributed.fsdp import cross_pod_mean, manual_pod
-from repro.distributed.mesh import make_mesh, shard_map
-mesh = make_mesh((2, 4), ("pod", "data"))
+from jax.sharding import AxisType
+mesh = jax.make_mesh((2, 4), ("pod", "data"), (AxisType.Auto,) * 2)
 
 def step(g):
     return cross_pod_mean(g, 2, n_chunks=2)
 
-f = jax.jit(shard_map(step, mesh=mesh, in_specs=P("pod"), out_specs=P("pod"),
+f = jax.jit(jax.shard_map(step, mesh=mesh, in_specs=P("pod"), out_specs=P("pod"),
                       axis_names={"pod"}, check_vma=False))
 x = jnp.arange(32.0).reshape(8, 4)
 got = np.asarray(f(x))
@@ -82,12 +82,12 @@ HLO_CHUNKS = """
 import jax, jax.numpy as jnp, functools, re
 from jax.sharding import PartitionSpec as P
 from repro.distributed import chunked as C
-from repro.distributed.mesh import make_mesh, shard_map
-mesh = make_mesh((8,), ("x",))
+from jax.sharding import AxisType
+mesh = jax.make_mesh((8,), ("x",), (AxisType.Auto,))
 x = jnp.zeros((64, 256), jnp.float32)
 
 def count_cp(nc):
-    f = jax.jit(shard_map(
+    f = jax.jit(jax.shard_map(
         functools.partial(C.chunked_all_gather, axis_name="x", axis_size=8, n_chunks=nc),
         mesh=mesh, in_specs=P("x"), out_specs=P(), check_vma=False))
     txt = f.lower(x).compile().as_text()
@@ -109,10 +109,10 @@ import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs.registry import build_model, ShapeCell
 from repro.launch.steps import build_train_step
-from repro.distributed.mesh import make_mesh
+from jax.sharding import AxisType
 from repro.optim import adamw
 
-mesh = make_mesh((2,2,2), ("pod","data","model"))
+mesh = jax.make_mesh((2,2,2), ("pod","data","model"), (AxisType.Auto,) * 3)
 cell = ShapeCell("t", 32, 8, "train")
 ocfg = adamw.AdamWConfig(lr=1e-2, warmup_steps=1)
 
@@ -149,9 +149,9 @@ SERVE_SPECS = """
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs.registry import build_model
-from repro.distributed.mesh import make_mesh
+from jax.sharding import AxisType
 
-mesh = make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"), (AxisType.Auto,) * 2)
 m = build_model("yi-34b", mesh, smoke=True)
 params = m.init_params(0)
 B, T = 4, 16

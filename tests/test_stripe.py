@@ -332,6 +332,23 @@ def test_checksum_many_words_matches_per_stream_and_host():
             fingerprint_bytes(raw[i].tobytes()).h
 
 
+def test_fingerprint_host_rows_divides_bucket_padding_out(monkeypatch):
+    """Rows padded to buckets, batches padded to k and rows split into
+    pieces all digest bit-equal to the host reference."""
+    pytest.importorskip("jax")
+    from repro.kernels import ops
+    from repro.kernels.checksum import TILE_BYTES
+    # two-tile pieces: long rows split, and the 40 short rows need 2 dispatches
+    monkeypatch.setattr(ops, "PIECE_BYTES", 2 * TILE_BYTES)
+    monkeypatch.setattr(ops, "BUCKETS", (TILE_BYTES, 2 * TILE_BYTES))
+    rng = np.random.default_rng(5)
+    sizes = [0, 1, 3, 4, TILE_BYTES, TILE_BYTES + 1, 2 * TILE_BYTES,
+             5 * TILE_BYTES + 13] + [777] * 40
+    rows = [rng.integers(0, 256, n, dtype=np.uint8) for n in sizes]
+    assert ops.fingerprint_host_rows(rows) == \
+        [fingerprint_bytes(r.tobytes()) for r in rows]
+
+
 # ---------------------------------------------------------------------------
 # satellite: fingerprint_many length validation
 # ---------------------------------------------------------------------------
