@@ -247,19 +247,30 @@ def read_back_fingerprint(
     pool: "BufferPool | None" = None,
     granule: int = DEFAULT_STREAM_GRANULE,
     fingerprint: Callable[[memoryview], Digest] | None = None,
+    tracer=_NULL_TRACER,
+    task: str = "",
+    lane: str = "",
 ) -> Digest:
     """Fingerprint the landed bytes, cheapest path first: in place via the
     destination's zero-copy ``read_back_view`` when it has one, else into a
     pooled buffer, else through the classic ``read_back()`` bytes. Shared by
     the integrity engine and the single-pass inline verifier.
     ``fingerprint`` replaces the host granule digest (the engine's device
-    backend passes its own)."""
+    backend passes its own). ``tracer`` gets a ``verify_readback`` span over
+    the lease and read of the landed bytes, the digest left out."""
     if fingerprint is None:
         def fingerprint(mv: memoryview) -> Digest:
             return fingerprint_view(mv, granule)
+
+    def read_done(t0: float) -> None:
+        tracer.add("verify_readback", "cksum", t0, time.perf_counter(),
+                   task=task, lane=lane, offset=offset)
+
+    t0 = time.perf_counter()
     viewfn = getattr(dest, "read_back_view", None)
     if viewfn is not None:
         mv = viewfn(offset, length)
+        read_done(t0)
         try:
             return fingerprint(mv)
         finally:
@@ -268,8 +279,10 @@ def read_back_fingerprint(
     if pool is not None:
         with pool.acquire(length) as buf:
             read_back_into(dest, offset, buf.view)
+            read_done(t0)
             return fingerprint(buf.view)
     back = dest.read_back(offset, length)
+    read_done(t0)
     return fingerprint(memoryview(back))
 
 
@@ -467,9 +480,6 @@ class IntegrityEngine:
         self._lag_hist = _metrics.REGISTRY.histogram(
             "verify_lag_seconds", "move-landed -> verified delay",
             ("task",), scale=1e-5)
-        self._verdicts = _metrics.REGISTRY.counter(
-            "verify_verdicts_total", "deferred verification verdicts",
-            ("task", "verdict"))
         self._q: "queue.Queue[VerifyJob | None]" = queue.Queue()
         self._lock = threading.Lock()
         self._idle = threading.Condition(self._lock)
@@ -584,10 +594,12 @@ class IntegrityEngine:
         small = [j for j in jobs if j.length <= self._fuse_max]
         big = [j for j in jobs if j.length > self._fuse_max]
         entries: list[dict] = []
+        lane = f"verifier{wid}"
         for job in small:
             ent: dict = {"job": job, "holders": [], "buf": None, "error": None,
                          "back": None, "src": None,
                          "back_dig": None, "src_dig": None}
+            t_read = time.perf_counter()
             try:
                 if job.expected is None:
                     mv = job.source.read_view(job.offset, job.length)
@@ -612,6 +624,9 @@ class IntegrityEngine:
                         raise IOError(
                             f"short read-back at {job.offset}: {len(data)}/{job.length}")
                     ent["back"] = np.frombuffer(data, dtype=np.uint8)
+                self._tracer.add("verify_readback", "cksum", t_read,
+                                 time.perf_counter(), task=self._task, lane=lane,
+                                 offset=job.offset)
             except BaseException as e:  # noqa: BLE001 — routed per job
                 ent["error"] = e
             entries.append(ent)
@@ -628,8 +643,11 @@ class IntegrityEngine:
                     rows.append(ent["src"])
                     slots.append((ent, "src_dig"))
         if rows:
+            live = [ent["job"] for ent in entries if ent["error"] is None]
+            span = ({"offset": live[0].offset} if len(live) == 1
+                    else {"jobs": len(live)})
             try:
-                digs = self._digest_rows(rows)
+                digs = self._digest_rows(rows, wid, **span)
                 for (ent, field), d in zip(slots, digs):
                     ent[field] = d
             except BaseException as e:  # noqa: BLE001 — poison the whole batch
@@ -688,8 +706,6 @@ class IntegrityEngine:
             "verify", "cksum", t0, t1, task=self._task,
             lane=f"verifier{wid}", offset=job.offset, ok=ok, fused=True)
         self._lag_hist.observe(lag, task=self._task)
-        self._verdicts.inc(1, task=self._task,
-                           verdict="ok" if ok else "corrupt")
         with self._lock:
             self.stats.cksum_seconds += ck
             self.stats.lag_seconds += lag
@@ -709,13 +725,16 @@ class IntegrityEngine:
             if self._on_error is not None:
                 self._on_error(job, e)
 
-    def _digest_rows(self, rows: list[np.ndarray]) -> list[Digest]:
+    def _digest_rows(self, rows: list[np.ndarray], wid: int, **span) -> list[Digest]:
         """The engine's one digest dispatch: device or host by backend, with
-        the digested bytes counted against the side that did the work."""
+        the digested bytes counted against the side that did the work.
+        ``span`` (the job's ``offset``, or ``jobs`` of a fused batch) goes on
+        the device dispatches' spans."""
         nbytes = sum(int(r.size) for r in rows)
         if self._backend == "pallas":
             from repro.kernels.ops import fingerprint_host_rows  # jax on demand
-            digs = fingerprint_host_rows(rows)
+            digs = fingerprint_host_rows(rows, tracer=self._tracer, task=self._task,
+                                         lane=f"verifier{wid}", **span)
             with self._lock:
                 self.stats.device_bytes += nbytes
             return digs
@@ -724,10 +743,11 @@ class IntegrityEngine:
             self.stats.host_bytes += nbytes
         return digs
 
-    def _fingerprint(self, mv: memoryview) -> Digest:
+    def _fingerprint(self, mv: memoryview, wid: int, offset: int) -> Digest:
         """Digest one whole job region (the per-job path)."""
         if self._backend == "pallas":
-            return self._digest_rows([np.frombuffer(mv, dtype=np.uint8)])[0]
+            return self._digest_rows([np.frombuffer(mv, dtype=np.uint8)], wid,
+                                     offset=offset)[0]
         with self._lock:
             self.stats.host_bytes += len(mv)
         return fingerprint_view(mv)
@@ -746,7 +766,7 @@ class IntegrityEngine:
                 # from the source's stable view (same bytes the mover wrote)
                 src_mv = job.source.read_view(job.offset, job.length)
                 try:
-                    job.expected = self._fingerprint(src_mv)
+                    job.expected = self._fingerprint(src_mv, wid, job.offset)
                 finally:
                     if isinstance(src_mv, memoryview):
                         src_mv.release()
@@ -755,7 +775,8 @@ class IntegrityEngine:
             # as a view; concurrent movers only touch disjoint offsets)
             actual = read_back_fingerprint(
                 job.dest, job.offset, job.length, pool=self._pool,
-                fingerprint=self._fingerprint)
+                fingerprint=lambda mv: self._fingerprint(mv, wid, job.offset),
+                tracer=self._tracer, task=self._task, lane=f"verifier{wid}")
         except BaseException as e:  # noqa: BLE001 — routed to the caller
             with self._lock:
                 self.stats.errors += 1
@@ -770,8 +791,6 @@ class IntegrityEngine:
             "verify", "cksum", t0, now, task=self._task,
             lane=f"verifier{wid}", offset=job.offset, ok=ok)
         self._lag_hist.observe(lag, task=self._task)
-        self._verdicts.inc(1, task=self._task,
-                           verdict="ok" if ok else "corrupt")
         with self._lock:
             self.stats.cksum_seconds += ck
             self.stats.lag_seconds += lag

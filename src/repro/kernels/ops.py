@@ -22,6 +22,7 @@ file digests in the checkpoint path.
 from __future__ import annotations
 
 import functools
+import time
 from typing import Sequence
 
 import numpy as np
@@ -31,6 +32,7 @@ import jax.numpy as jnp
 from repro.core.integrity import BASES, EMPTY_DIGEST, NBASES, P, Digest
 from repro.kernels import checksum as _ck
 from repro.kernels import matmul_digest as _mm
+from repro.obs.trace import NULL, Tracer
 
 
 def _pow_mod(base: int, exp: int) -> int:
@@ -162,7 +164,8 @@ def _unpad_vector(pad: int) -> np.ndarray:
     return vec
 
 
-def fingerprint_host_rows(rows: Sequence[np.ndarray]) -> list[Digest]:
+def fingerprint_host_rows(rows: Sequence[np.ndarray], *, tracer: Tracer = NULL,
+                          task: str = "", lane: str = "", **span) -> list[Digest]:
     """Digests of 1-D uint8 host rows, every byte digested on the device.
 
     Rows are cut into pieces of at most ``PIECE_BYTES``. Each piece is
@@ -172,28 +175,53 @@ def fingerprint_host_rows(rows: Sequence[np.ndarray]) -> list[Digest]:
     The device therefore sees at most ``len(BUCKETS)`` shapes, however
     ragged the input. The padding is divided back out exactly (the
     ``_unpad_residues`` identity) and a row's pieces merge by the merge law.
+
+    Each dispatch records four ``cksum`` spans on ``tracer`` (``task``,
+    ``lane``, and ``bucket``, ``rows`` plus ``span`` as args), each inside a
+    ``jax.profiler.TraceAnnotation`` of its name: ``digest_stage`` (zeroed
+    stage and copies), ``digest_put`` (host-to-device array and kernel
+    enqueue), ``digest_wait`` (the blocking fetch of the residues) and
+    ``digest_unpad`` (unpadding, and merging the rows this dispatch
+    completes). They time what the dispatch does anyway: nothing waits on
+    the device for their sake.
     """
     todo: dict[int, list[tuple[int, int, int]]] = {}   # bucket -> (row, start, n)
     for i, r in enumerate(rows):
         for s in range(0, r.size, PIECE_BYTES):
             n = min(PIECE_BYTES, r.size - s)
             todo.setdefault(bucket_of(n), []).append((i, s, n))
+    left = [-(-r.size // PIECE_BYTES) for r in rows]    # pieces still to digest
     parts: dict[tuple[int, int], Digest] = {}
+    out = [EMPTY_DIGEST] * len(rows)
     for bucket, pieces in todo.items():
         k = batch_rows(bucket)
         for b0 in range(0, len(pieces), k):
             batch = pieces[b0:b0 + k]
-            stage = np.zeros((k, bucket), np.uint8)
-            for j, (i, s, n) in enumerate(batch):
-                stage[j, :n] = rows[i][s:s + n]
-            res = np.asarray(_checksum_many(jnp.asarray(stage.view(np.int32))))
-            for j, (i, s, n) in enumerate(batch):
-                h = res[j].astype(np.int64) * _unpad_vector(bucket - n) % P
-                parts[(i, s)] = Digest(tuple(int(v) for v in h), n)
-    out = []
-    for i, r in enumerate(rows):
-        d = EMPTY_DIGEST
-        for s in range(0, r.size, PIECE_BYTES):
-            d = d.merge(parts[(i, s)])
-        out.append(d)
+            t0 = time.perf_counter()
+            with jax.profiler.TraceAnnotation("digest_stage"):
+                stage = np.zeros((k, bucket), np.uint8)
+                for j, (i, s, n) in enumerate(batch):
+                    stage[j, :n] = rows[i][s:s + n]
+            t1 = time.perf_counter()
+            with jax.profiler.TraceAnnotation("digest_put"):
+                res = _checksum_many(jnp.asarray(stage.view(np.int32)))
+            t2 = time.perf_counter()
+            with jax.profiler.TraceAnnotation("digest_wait"):
+                res = np.asarray(res)
+            t3 = time.perf_counter()
+            with jax.profiler.TraceAnnotation("digest_unpad"):
+                for j, (i, s, n) in enumerate(batch):
+                    h = res[j].astype(np.int64) * _unpad_vector(bucket - n) % P
+                    parts[(i, s)] = Digest(tuple(int(v) for v in h), n)
+                    left[i] -= 1
+                    if left[i] == 0:
+                        d = EMPTY_DIGEST
+                        for s2 in range(0, rows[i].size, PIECE_BYTES):
+                            d = d.merge(parts.pop((i, s2)))
+                        out[i] = d
+            t4 = time.perf_counter()
+            for name, a, b in (("digest_stage", t0, t1), ("digest_put", t1, t2),
+                               ("digest_wait", t2, t3), ("digest_unpad", t3, t4)):
+                tracer.add(name, "cksum", a, b, task=task, lane=lane,
+                           bucket=bucket, rows=len(batch), **span)
     return out

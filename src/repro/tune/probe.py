@@ -150,7 +150,8 @@ def sample_from_chain(chain, *, length: int = 0) -> ChunkSample:
             if sp.lane.startswith("mover") and sp.lane[5:].isdigit():
                 mover = int(sp.lane[5:])
         elif sp.cat == "cksum":
-            if sp.name != "verify":        # off-path verification is lag-side
+            # off-path verification (the engine's verifier lanes) is lag-side
+            if sp.name != "verify" and not sp.lane.startswith("verifier"):
                 cksum_s += sp.dur
         elif sp.cat == "cksum_wait":
             lag_s += sp.dur

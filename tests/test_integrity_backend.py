@@ -5,6 +5,9 @@ byte on the device — fused drains, singleton drains, ragged lengths and jobs
 over ``fuse_max_bytes`` alike — and land results bit-equal to the host
 reference (``fingerprint_bytes``).
 """
+import threading
+import time
+
 import numpy as np
 import pytest
 
@@ -124,3 +127,168 @@ def test_interpret_resolves_from_platform(monkeypatch, platform, want):
             ck.resolve_interpret(None)
     else:
         assert ck.resolve_interpret(None) is want
+
+
+# ---------------------------------------------------------------------------
+# spans of the verify path: read-back and each device dispatch
+# ---------------------------------------------------------------------------
+DISPATCH = ("digest_stage", "digest_put", "digest_wait", "digest_unpad")
+VERIFY_CHILDREN = ("verify_readback",) + DISPATCH
+
+
+def test_each_dispatch_records_its_four_spans(monkeypatch):
+    from repro.obs import NULL, Tracer
+
+    # two-tile pieces: 42 one-tile pieces need two dispatches, 5 two-tile one
+    monkeypatch.setattr(ops, "PIECE_BYTES", 2 * TILE)
+    monkeypatch.setattr(ops, "BUCKETS", (TILE, 2 * TILE))
+    rng = np.random.default_rng(13)
+    sizes = [0, 1, TILE, TILE + 1, 2 * TILE, 5 * TILE + 13] + [777] * 40
+    rows = [rng.integers(0, 256, n, dtype=np.uint8) for n in sizes]
+    tr = Tracer()
+    got = ops.fingerprint_host_rows(rows, tracer=tr, task="t", lane="verifier1", offset=7)
+    assert got == ops.fingerprint_host_rows(rows, tracer=NULL)
+    assert got == [fingerprint_bytes(r.tobytes()) for r in rows]
+    spans = tr.spans("t")
+    assert len(spans) == 4 * 3
+    want = sorted([(TILE, 32), (TILE, 10), (2 * TILE, 5)])
+    for name in DISPATCH:
+        mine = [s for s in spans if s.name == name]
+        assert sorted((s.arg("bucket"), s.arg("rows")) for s in mine) == want
+        assert all(s.cat == "cksum" and s.lane == "verifier1" and s.arg("offset") == 7
+                   for s in mine)
+    # a dispatch's phases follow each other, in the order of the table
+    for i in range(0, len(spans), 4):
+        four = spans[i:i + 4]
+        assert [s.name for s in four] == list(DISPATCH)
+        assert all(a.t1 <= b.t0 for a, b in zip(four, four[1:]))
+        assert len({(s.arg("bucket"), s.arg("rows")) for s in four}) == 1
+
+
+class _Landed:
+    """Landed bytes read back into pooled buffers (no zero-copy view); the
+    read of ``hold`` waits for ``release``, so jobs can queue behind it."""
+
+    def __init__(self, payload: bytes, hold: int | None = None):
+        self.buf, self.hold = payload, hold
+        self.entered, self.release = threading.Event(), threading.Event()
+
+    def read_back_into(self, offset, view):
+        if offset == self.hold:
+            self.entered.set()
+            assert self.release.wait(60)
+        view[:] = self.buf[offset:offset + len(view)]
+        return len(view)
+
+    def read_back(self, offset, length):
+        return self.buf[offset:offset + length]
+
+
+def _engine_spans(fuse: bool):
+    """A pallas engine's spans over five ragged jobs; with ``fuse`` the last
+    four queue behind the first and drain as one fused batch."""
+    from repro.core.dataplane import BufferPool, IntegrityEngine, VerifyJob
+    from repro.obs import Tracer
+
+    lengths = [TILE + 7, TILE - 3, 2 * TILE + 1, 777, TILE]
+    offsets = [sum(lengths[:i]) for i in range(len(lengths))]
+    payload = _payload(17, sum(lengths))
+    dest = _Landed(payload, hold=0 if fuse else None)
+    tr, verdicts = Tracer(), []
+    eng = IntegrityEngine(workers=1, pool=BufferPool(4 * TILE), tracer=tr, task="t",
+                          fuse=fuse, batch=8, backend="pallas",
+                          on_verified=lambda job, lag, ck: verdicts.append(job.offset),
+                          on_corrupt=lambda job, actual, lag: None)
+
+    def submit(i):
+        off, n = offsets[i], lengths[i]
+        assert eng.submit(VerifyJob(key=i, offset=off, length=n, dest=dest,
+                                    expected=fingerprint_bytes(payload[off:off + n]),
+                                    enqueued_s=time.perf_counter()))
+
+    try:
+        submit(0)
+        if fuse:
+            assert dest.entered.wait(60)
+        for i in range(1, len(lengths)):
+            submit(i)
+        dest.release.set()
+        assert eng.drain(timeout=120)
+    finally:
+        eng.close()
+    assert sorted(verdicts) == offsets
+    assert eng.stats.fused_batches == int(fuse)
+    assert eng.stats.fused_jobs == (4 if fuse else 0)
+    return tr, offsets
+
+
+@pytest.mark.parametrize("fuse", [False, True], ids=["per_job", "fused"])
+def test_engine_records_one_readback_per_job_inside_its_verify(fuse):
+    tr, offsets = _engine_spans(fuse)
+    spans = tr.spans("t")
+    assert all(s.lane == "verifier0" for s in spans)
+    for off in offsets:
+        chain = tr.chunk_chain("t", off)
+        names = [s.name for s in chain]
+        assert names.count("verify_readback") == 1
+        (wait,) = [s for s in chain if s.name == "verify_wait"]
+        (verify,) = [s for s in chain if s.name == "verify"]
+        if fuse and off:
+            # a fused job's verify is a slice of its batch's interval and its
+            # dispatches carry the batch, not the job (checked below)
+            assert not set(DISPATCH) & set(names)
+            continue
+        assert wait.t1 <= verify.t0
+        # the per-job path: its read-back and dispatches, between its
+        # verify_wait and the end of its verify
+        assert [n for n in names if n in DISPATCH] == list(DISPATCH)
+        for s in chain:
+            if s.name in VERIFY_CHILDREN:
+                assert verify.t0 <= s.t0 <= s.t1 <= verify.t1
+    if fuse:
+        batch = [s for s in spans if s.name == "verify" and s.arg("fused")]
+        assert len(batch) == 4
+        lo, hi = min(s.t0 for s in batch), max(s.t1 for s in batch)
+        fused = [s for s in spans if s.arg("jobs") is not None]
+        # two buckets among the four jobs: two dispatches
+        assert [s.name for s in fused] == list(DISPATCH) * 2
+        assert all(s.arg("jobs") == 4 and s.arg("offset") is None for s in fused)
+        reads = [s for s in spans if s.name == "verify_readback" and s.arg("offset")]
+        assert len(reads) == 4
+        assert all(lo <= s.t0 <= s.t1 <= hi for s in fused + reads)
+
+
+@pytest.mark.parametrize("fuse", [False, True], ids=["per_job", "fused"])
+def test_child_spans_leave_the_attribution_unchanged(fuse):
+    from repro.obs import attribute
+
+    tr, _ = _engine_spans(fuse)
+    spans = tr.spans()
+    bare = [s for s in spans if s.name not in VERIFY_CHILDREN]
+    assert len(bare) < len(spans)
+    a, b = attribute(spans), attribute(bare)
+    assert a.makespan_s == b.makespan_s
+    assert a.seconds == pytest.approx(b.seconds, abs=1e-12)
+    assert a.shares() == pytest.approx(b.shares(), abs=1e-12)
+
+
+def test_host_backend_records_readback_but_no_dispatch():
+    from repro.core.dataplane import BufferPool, IntegrityEngine, VerifyJob
+    from repro.obs import Tracer
+
+    payload = _payload(19, 3 * TILE)
+    tr = Tracer()
+    eng = IntegrityEngine(workers=1, pool=BufferPool(TILE), tracer=tr, task="t",
+                          fuse=False, on_verified=lambda *a: None,
+                          on_corrupt=lambda *a: None)
+    try:
+        for i in range(3):
+            eng.submit(VerifyJob(key=i, offset=i * TILE, length=TILE, dest=_Landed(payload),
+                                 expected=fingerprint_bytes(payload[i * TILE:(i + 1) * TILE]),
+                                 enqueued_s=0.0))
+        assert eng.drain(timeout=60)
+    finally:
+        eng.close()
+    names = [s.name for s in tr.spans("t")]
+    assert names.count("verify_readback") == 3 and names.count("verify") == 3
+    assert not set(DISPATCH) & set(names)

@@ -345,6 +345,23 @@ def test_probe_sample_derived_from_span_chain():
         sample_from_chain([])
 
 
+def test_probe_leaves_the_verifier_lanes_out_of_the_movers_cksum():
+    from repro.tune.probe import sample_from_chain
+    tr = Tracer()
+    tr.add("move", "wire", 0.0, 1.0, task="t", lane="mover0", offset=0)
+    tr.add("cksum_inline", "cksum", 1.0, 1.25, task="t", lane="mover0", offset=0)
+    tr.add("verify_wait", "cksum_wait", 1.25, 2.0, task="t", lane="verifier0", offset=0)
+    tr.add("verify", "cksum", 2.0, 3.0, task="t", lane="verifier0", offset=0)
+    for name, a in (("verify_readback", 2.0), ("digest_stage", 2.25),
+                    ("digest_put", 2.5), ("digest_wait", 2.75)):
+        tr.add(name, "cksum", a, a + 0.25, task="t", lane="verifier0", offset=0)
+    s = sample_from_chain(tr.chunk_chain("t", 0), length=4096)
+    # the engine's work is off the mover path: lag-side, not cksum time
+    assert s.cksum_seconds == pytest.approx(0.25)
+    assert s.attempt_seconds == pytest.approx(1.25)
+    assert s.cksum_lag_s == pytest.approx(0.75)
+
+
 # ---------------------------------------------------------------------------
 # flight recorder
 # ---------------------------------------------------------------------------
