@@ -22,7 +22,9 @@ file digests in the checkpoint path.
 from __future__ import annotations
 
 import functools
+import gc
 import time
+import weakref
 from typing import Sequence
 
 import numpy as np
@@ -32,6 +34,7 @@ import jax.numpy as jnp
 from repro.core.integrity import BASES, EMPTY_DIGEST, NBASES, P, Digest
 from repro.kernels import checksum as _ck
 from repro.kernels import matmul_digest as _mm
+from repro.obs.metrics import REGISTRY
 from repro.obs.trace import NULL, Tracer
 
 
@@ -137,13 +140,19 @@ def matmul_with_digest(
 # host rows -> bucketed device dispatches (the served integrity path)
 # ---------------------------------------------------------------------------
 PIECE_BYTES = 8 * 1024 * 1024    # largest bucket; longer rows go as pieces
-BATCH_BYTES = 32 * 1024 * 1024   # staged bytes per dispatch
+BATCH_BYTES = 32 * 1024 * 1024   # bytes per dispatch
 BATCH_ROWS = 32                  # rows per dispatch for buckets <= 1 MiB
 # every padded row length the device is handed: TILE_BYTES * 2^i
 BUCKETS = tuple(_ck.TILE_BYTES << i
                 for i in range((PIECE_BYTES // _ck.TILE_BYTES).bit_length()))
 
 _checksum_many = jax.jit(_ck.checksum_many_words, static_argnames=("rows", "interpret"))
+
+_M_DISPATCHES = REGISTRY.counter(
+    "digest_dispatches_total",
+    "device digest dispatches, by how the batch reached the device: read in "
+    "place from the caller's row (view) or copied into a zeroed stage (staged)",
+    ("path",))
 
 
 def bucket_of(nbytes: int) -> int:
@@ -154,6 +163,40 @@ def bucket_of(nbytes: int) -> int:
 def batch_rows(bucket: int) -> int:
     """The fixed row count k of every dispatch at ``bucket``."""
     return max(1, min(BATCH_ROWS, BATCH_BYTES // bucket))
+
+
+def _row_view(rows: Sequence[np.ndarray], batch: list[tuple[int, int, int]],
+              bucket: int, k: int) -> np.ndarray | None:
+    """The batch as a zero-copy ``(k, bucket // 4)`` int32 view of its row,
+    or ``None`` when it has to be staged: it is that view only when it holds
+    k pieces that each fill the bucket, back to back in one C-contiguous row,
+    starting at a 4-byte aligned address."""
+    i, s, _n = batch[0]
+    if len(batch) != k or any(piece != (i, s + m * bucket, bucket)
+                              for m, piece in enumerate(batch)):
+        return None
+    row = rows[i]
+    if not row.flags.c_contiguous or (row.ctypes.data + s) % 4:
+        return None
+    return row[s:s + k * bucket].view(np.int32).reshape(k, bucket // 4)
+
+
+def _await_release(sent: weakref.ref, timeout_s: float = 10.0) -> None:
+    """Wait until the runtime drops the host array a dispatch was sent from.
+
+    It lets go once the transfer is done, from a thread of its own and
+    without the GIL, so the reference may be queued for jax's gc callback a
+    little after the residues are back; a young collection runs the callback.
+    """
+    deadline = time.perf_counter() + timeout_s
+    while sent() is not None:
+        gc.collect(0)
+        if sent() is None:
+            return
+        if time.perf_counter() > deadline:
+            raise RuntimeError(f"the runtime still holds a digest's host rows "
+                               f"{timeout_s} s after its residues returned")
+        time.sleep(1e-4)
 
 
 @functools.lru_cache(maxsize=1 << 12)
@@ -168,22 +211,35 @@ def fingerprint_host_rows(rows: Sequence[np.ndarray], *, tracer: Tracer = NULL,
                           task: str = "", lane: str = "", **span) -> list[Digest]:
     """Digests of 1-D uint8 host rows, every byte digested on the device.
 
-    Rows are cut into pieces of at most ``PIECE_BYTES``. Each piece is
-    zero-padded on the host to its bucket (a power-of-two number of kernel
-    tiles) and handed over as int32 words, ``batch_rows(bucket)`` pieces per
-    ``checksum_many_words`` dispatch; unused rows stay zero and are dropped.
-    The device therefore sees at most ``len(BUCKETS)`` shapes, however
-    ragged the input. The padding is divided back out exactly (the
-    ``_unpad_residues`` identity) and a row's pieces merge by the merge law.
+    Rows are cut into pieces of at most ``PIECE_BYTES`` and handed over as
+    int32 words, ``batch_rows(bucket)`` pieces per ``checksum_many_words``
+    dispatch, where a piece's bucket is a power-of-two number of kernel tiles
+    that holds it. A batch of k pieces that each fill the bucket, back to
+    back in one C-contiguous row at a 4-byte aligned address, goes to the
+    device as a view of that row, with no host copy. Every other batch is
+    staged: zero-padded on the host into a fresh ``(k, bucket)`` array whose
+    unused rows stay zero and are dropped. Either way the device sees at most
+    ``len(BUCKETS)`` shapes, however ragged the input. The padding is divided
+    back out exactly (the ``_unpad_residues`` identity) and a row's pieces
+    merge by the merge law. ``digest_dispatches_total{path=view|staged}``
+    counts the dispatches of each kind.
+
+    The runtime reads a view from the caller's rows, which must stay alive
+    and unwritten until this returns. By then the residues have been fetched
+    (so the kernel has read its input), the device input is deleted, and the
+    runtime has dropped its reference to the rows (``_await_release``): the
+    caller may release, reuse or resize their buffers.
 
     Each dispatch records four ``cksum`` spans on ``tracer`` (``task``,
     ``lane``, and ``bucket``, ``rows`` plus ``span`` as args), each inside a
     ``jax.profiler.TraceAnnotation`` of its name: ``digest_stage`` (zeroed
-    stage and copies), ``digest_put`` (host-to-device array and kernel
-    enqueue), ``digest_wait`` (the blocking fetch of the residues) and
-    ``digest_unpad`` (unpadding, and merging the rows this dispatch
-    completes). They time what the dispatch does anyway: nothing waits on
-    the device for their sake.
+    stage and copies, or nothing for a view; its ``staged_bytes`` arg is
+    the stage's size, 0 for a view), ``digest_put`` (host-to-device array
+    and kernel enqueue), ``digest_wait`` (the blocking fetch of the residues,
+    then the device input deleted and, for a view, the runtime's reference to
+    the rows awaited) and ``digest_unpad`` (unpadding, and merging the rows
+    this dispatch completes). They time what the dispatch does anyway:
+    nothing waits on the device for their sake.
     """
     todo: dict[int, list[tuple[int, int, int]]] = {}   # bucket -> (row, start, n)
     for i, r in enumerate(rows):
@@ -199,15 +255,25 @@ def fingerprint_host_rows(rows: Sequence[np.ndarray], *, tracer: Tracer = NULL,
             batch = pieces[b0:b0 + k]
             t0 = time.perf_counter()
             with jax.profiler.TraceAnnotation("digest_stage"):
-                stage = np.zeros((k, bucket), np.uint8)
-                for j, (i, s, n) in enumerate(batch):
-                    stage[j, :n] = rows[i][s:s + n]
+                host = _row_view(rows, batch, bucket, k)
+                staged_bytes = 0 if host is not None else k * bucket
+                if staged_bytes:
+                    stage = np.zeros((k, bucket), np.uint8)
+                    for j, (i, s, n) in enumerate(batch):
+                        stage[j, :n] = rows[i][s:s + n]
+                    host = stage.view(np.int32)
             t1 = time.perf_counter()
             with jax.profiler.TraceAnnotation("digest_put"):
-                res = _checksum_many(jnp.asarray(stage.view(np.int32)))
+                words = jnp.asarray(host)
+                res = _checksum_many(words)
             t2 = time.perf_counter()
             with jax.profiler.TraceAnnotation("digest_wait"):
                 res = np.asarray(res)
+                words.delete()                   # the kernel has read it
+                sent = weakref.ref(host)
+                del words, host
+                if not staged_bytes:
+                    _await_release(sent)
             t3 = time.perf_counter()
             with jax.profiler.TraceAnnotation("digest_unpad"):
                 for j, (i, s, n) in enumerate(batch):
@@ -220,8 +286,11 @@ def fingerprint_host_rows(rows: Sequence[np.ndarray], *, tracer: Tracer = NULL,
                             d = d.merge(parts.pop((i, s2)))
                         out[i] = d
             t4 = time.perf_counter()
-            for name, a, b in (("digest_stage", t0, t1), ("digest_put", t1, t2),
-                               ("digest_wait", t2, t3), ("digest_unpad", t3, t4)):
+            _M_DISPATCHES.inc(path="staged" if staged_bytes else "view")
+            tracer.add("digest_stage", "cksum", t0, t1, task=task, lane=lane,
+                       bucket=bucket, rows=len(batch), staged_bytes=staged_bytes, **span)
+            for name, a, b in (("digest_put", t1, t2), ("digest_wait", t2, t3),
+                               ("digest_unpad", t3, t4)):
                 tracer.add(name, "cksum", a, b, task=task, lane=lane,
                            bucket=bucket, rows=len(batch), **span)
     return out

@@ -165,6 +165,140 @@ def test_each_dispatch_records_its_four_spans(monkeypatch):
         assert len({(s.arg("bucket"), s.arg("rows")) for s in four}) == 1
 
 
+# ---------------------------------------------------------------------------
+# dispatch path: a full contiguous batch is a view of its row, others staged
+# ---------------------------------------------------------------------------
+PIECE = 2 * TILE          # shrunk PIECE_BYTES: k = 4 full pieces, k = 8 tiles
+
+
+@pytest.fixture
+def view_pieces(monkeypatch):
+    monkeypatch.setattr(ops, "PIECE_BYTES", PIECE)
+    monkeypatch.setattr(ops, "BUCKETS", (TILE, PIECE))
+    monkeypatch.setattr(ops, "BATCH_BYTES", 4 * PIECE)
+    assert ops.batch_rows(PIECE) == 4 and ops.batch_rows(TILE) == 8
+
+
+def _bytes_row(seed, n):
+    return np.random.default_rng(seed).integers(0, 256, n, dtype=np.uint8)
+
+
+def _odd_row(n):
+    buf = bytearray(n + 1)
+    buf[1:] = _bytes_row(5, n).tobytes()
+    return np.frombuffer(buf, dtype=np.uint8)[1:]
+
+
+# name -> (rows, staged_bytes of each dispatch in order); a full batch is
+# 4 x PIECE, a staged one costs its (k, bucket) stage
+VIEW_CASES = {
+    "k_full": (lambda: [_bytes_row(1, 4 * PIECE)], [0]),
+    "k_plus_one": (lambda: [_bytes_row(2, 5 * PIECE)], [0, 4 * PIECE]),
+    "ragged_tail": (lambda: [_bytes_row(3, 4 * PIECE + 777)], [0, 8 * TILE]),
+    # pieces 0-1 of row 0 and 0-1 of row 1 share a batch; row 1's 2-5 do not
+    "across_rows": (lambda: [_bytes_row(4, 2 * PIECE), _bytes_row(6, 6 * PIECE)],
+                    [4 * PIECE, 0]),
+    "odd_address": (lambda: [_odd_row(4 * PIECE)], [4 * PIECE]),
+    "strided": (lambda: [_bytes_row(7, 8 * PIECE)[::2]], [4 * PIECE]),
+    "read_only": (lambda: [np.frombuffer(_bytes_row(8, 4 * PIECE + 1000).tobytes(),
+                                         dtype=np.uint8)], [0, 8 * TILE]),
+}
+
+
+def _dispatches(monkeypatch, rows):
+    """Digests, the digest_stage spans' staged_bytes, the arrays handed to the
+    kernel as (shape, dtype), and the counter's (view, staged) increments."""
+    from repro.obs import Tracer
+
+    handed, real = [], ops._checksum_many
+
+    def recording(words, **kw):
+        handed.append((words.shape, words.dtype))
+        return real(words, **kw)
+
+    monkeypatch.setattr(ops, "_checksum_many", recording)
+    before = [ops._M_DISPATCHES.value(path=p) for p in ("view", "staged")]
+    tr = Tracer()
+    got = ops.fingerprint_host_rows(rows, tracer=tr, task="t")
+    monkeypatch.setattr(ops, "_checksum_many", real)
+    counts = tuple(ops._M_DISPATCHES.value(path=p) - b
+                   for p, b in zip(("view", "staged"), before))
+    staged = [s.arg("staged_bytes") for s in tr.spans("t") if s.name == "digest_stage"]
+    return got, staged, handed, counts
+
+
+@pytest.mark.parametrize("case", list(VIEW_CASES))
+def test_full_contiguous_batches_go_as_views(case, view_pieces, monkeypatch):
+    make, want_staged = VIEW_CASES[case]
+    rows = make()
+    assert all(r.ctypes.data % 4 == 0 for r in rows) == (case != "odd_address")
+    got, staged, handed, counts = _dispatches(monkeypatch, rows)
+    assert got == [fingerprint_bytes(r.tobytes()) for r in rows]
+    assert staged == want_staged
+    assert counts == (want_staged.count(0), len(want_staged) - want_staged.count(0))
+    # today's staged result: every batch staged, the same digests and shapes
+    monkeypatch.setattr(ops, "_row_view", lambda *a: None)
+    all_staged, staged2, handed2, counts2 = _dispatches(monkeypatch, rows)
+    assert all_staged == got
+    assert all(b > 0 for b in staged2) and counts2 == (0, len(want_staged))
+    assert handed == handed2
+    assert all(dt == np.int32 and shape[1] * 4 in (TILE, PIECE)
+               and shape[0] == ops.batch_rows(shape[1] * 4) for shape, dt in handed)
+
+
+@pytest.mark.parametrize("where", ["pooled", "one_shot", "aligned_row"])
+def test_no_reference_to_the_rows_outlives_the_digest(where, view_pieces, monkeypatch):
+    """The rows' buffers can be released and resized as soon as the digest
+    returns. ``aligned_row`` sits at a 64-byte boundary, where the CPU
+    runtime reads the host buffer in place instead of copying it."""
+    from repro.core import dataplane
+    from repro.core.dataplane import BufferPool, IntegrityEngine, read_back_fingerprint
+
+    leased = []
+
+    class Recorded(dataplane.ChunkBuffer):
+        __slots__ = ()
+
+        def __init__(self, pool, raw, length):
+            super().__init__(pool, raw, length)
+            leased.append(raw)
+
+    monkeypatch.setattr(dataplane, "ChunkBuffer", Recorded)
+    n = 4 * PIECE
+    payload = _payload(23, 2 * n)
+    pool = BufferPool(n if where == "pooled" else n // 2, capacity=1)
+    eng = IntegrityEngine(workers=1, pool=pool, backend="pallas",
+                          on_verified=lambda *a: None, on_corrupt=lambda *a: None)
+    before = ops._M_DISPATCHES.value(path="view")
+    try:
+        for i in range(3):
+            off = i * n // 2
+            want = fingerprint_bytes(payload[off:off + n])
+            if where == "aligned_row":
+                raw = bytearray(n + 64)
+                mv = memoryview(raw)
+                row = np.frombuffer(mv, dtype=np.uint8)
+                a = -row.ctypes.data % 64
+                row[a:a + n] = np.frombuffer(payload[off:off + n], dtype=np.uint8)
+                assert ops.fingerprint_host_rows([row[a:a + n]]) == [want]
+                del row
+                mv.release()
+                leased.append(raw)
+            else:
+                got = read_back_fingerprint(
+                    _Landed(payload), off, n, pool=pool,
+                    fingerprint=lambda mv: eng._fingerprint(mv, 0, off))
+                assert got == want
+            for raw in leased:                  # raises BufferError if exported
+                raw.append(0)
+                raw.pop()
+    finally:
+        eng.close()
+    assert ops._M_DISPATCHES.value(path="view") - before == 3
+    assert len(leased) == 3
+    assert pool.stats.oversize == (3 if where == "one_shot" else 0)
+
+
 class _Landed:
     """Landed bytes read back into pooled buffers (no zero-copy view); the
     read of ``hold`` waits for ``release``, so jobs can queue behind it."""
