@@ -73,6 +73,12 @@ class Deployment:
             self._task_ids.extend(req.task_ids)
         self.svc.wait_all(req.task_ids, timeout=timeout)
 
+    def counters(self) -> dict:
+        """The program's metrics now."""
+        from repro.obs.metrics import REGISTRY
+
+        return REGISTRY.snapshot()
+
     def at_close(self) -> list:
         """Status of every task submitted so far, read at the window's close."""
         with self._lock:
@@ -96,15 +102,18 @@ class Deployment:
         return out
 
     def stop(self) -> tuple[dict, list]:
-        """Close the service; the final status of every task and its spans."""
+        """Close the service; the final status of every task and its spans as
+        ``(cat, name, t0, t1, args)``, ``args`` holding the span's ``task`` and
+        ``lane`` besides its own."""
         try:
             with self._lock:
                 ids = list(self._task_ids)
             final = {st.task_id: st for st in self.svc.status_many(ids)}
-            spans = [(s.cat, s.t0, s.t1) for s in self.svc.tracer.spans()]
+            records = [(s.cat, s.name, s.t0, s.t1, dict(s.args, task=s.task, lane=s.lane))
+                       for s in self.svc.tracer.spans()]
         finally:
             self.svc.close()
-        return final, spans
+        return final, records
 
     def check(self, requests: list, final: dict, log) -> dict:
         """The reference's numbers for every request (limit 0 each)."""

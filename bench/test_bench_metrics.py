@@ -2,7 +2,6 @@
 from __future__ import annotations
 
 import concurrent.futures as cf
-import os
 import types
 
 import numpy as np
@@ -212,9 +211,13 @@ def test_phase_seconds_matches_the_service_attribution():
     assert sum(mine.values()) == pytest.approx(8.0)
 
 
+SPANS = [("wire", 0.0, 4.0), ("cksum", 3.0, 6.0), ("journal", 6.0, 7.0)]
+
+
 def _obs(device=None, **kw):
-    spans = [("wire", 0.0, 4.0), ("cksum", 3.0, 6.0), ("journal", 6.0, 7.0)]
-    base = dict(t0=0.0, t1=10.0, spans=spans,
+    records = [(cat, f"span{i}", a, b, {"task": "t", "lane": "mover0"})
+               for i, (cat, a, b) in enumerate(SPANS)]
+    base = dict(t0=0.0, t1=10.0, records=records,
                 device_bytes=int(8.19e9), device=device, peaks={"hbm_bytes_per_s": 819e9})
     base.update(kw)
     return harness.Observations(**base)
@@ -240,9 +243,39 @@ def test_readers_on_synthetic_observations():
     assert read["digest_roofline.dtn"](_obs(red)) is None
 
 
-def test_every_reader_is_importable_by_file():
-    names = [f[:-3] for f in os.listdir(os.path.join(harness.BENCH, "metrics"))
-             if f.endswith(".py")]
-    assert len(names) == 4
-    for n in names:
-        assert callable(harness.load_reader(n))
+def test_the_span_readers_read_the_same_from_records_as_from_spans():
+    # the four accepted readers read (cat, t0, t1) spans; from records they
+    # get the same spans, and the same numbers as from the spans alone
+    import types
+
+    red = devtrace.DeviceReduction(window_s=10.0, busy_s=0.5, op_s={},
+                                   kernel_s={"checksum_many_words": 0.4},
+                                   roles={"checksum_many_words": "integrity_digest"},
+                                   gaps=[])
+    with_records = _obs(red)
+    assert with_records.spans == SPANS
+    spans_alone = types.SimpleNamespace(t0=0.0, t1=10.0, window_s=10.0, spans=SPANS,
+                                        device_bytes=int(8.19e9), device=red,
+                                        peaks={"hbm_bytes_per_s": 819e9})
+    for n in ("wire_share.dtn", "cksum_share.dtn", "device_idle_share.dtn",
+              "digest_roofline.dtn"):
+        read = harness.load_reader(n)
+        assert read(with_records) == read(spans_alone), n
+
+
+def test_counter_change_counts_new_series_from_zero():
+    from repro.obs.metrics import Registry
+
+    reg = Registry()
+    fam = reg.counter("digest_dispatches_total", labels=("path",))
+    reg.counter("requests_total").inc(4)
+    reg.gauge("queue_depth").set(9)
+    fam.inc(3, path="view")
+    before = reg.snapshot()
+    fam.inc(7, path="view")
+    fam.inc(2, path="staged")
+    reg.gauge("queue_depth").set(2)
+    assert harness.window_counters(before, reg.snapshot()) == {
+        ("digest_dispatches_total", "view"): 7.0,
+        ("digest_dispatches_total", "staged"): 2.0,
+        ("requests_total", ""): 0.0}

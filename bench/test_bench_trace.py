@@ -9,7 +9,10 @@ import pytest
 from bench import devtrace, harness
 
 KERNELS = harness.load_kernels()
-DIGEST = [k["name"] for k in KERNELS if k["role"] == "integrity_digest"]
+# the kernel file that names the served digest's events, whatever other
+# kernel files are added beside it
+DIGEST = [k["name"] for k in KERNELS if k["role"] == "integrity_digest"
+          and devtrace.matches(k, "chunk_checksum_many.1")]
 RECORDED = os.path.join(harness.BENCH, "testdata", "trace_small.json")
 
 

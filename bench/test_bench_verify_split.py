@@ -85,6 +85,43 @@ def test_offsets_pair_each_span_with_its_annotation():
     assert verify_split.offsets(recs, [], 1e12, 5.0, 0.0, 100.0) is None
 
 
+# the verify path's readers and what each reads on ``_records()`` over
+# [0, 10] s with 2 GB handed to the device
+READERS = {"mover_digest_s_per_GB.dtn": 0.5, "verify_readback_s_per_GB.dtn": 0.5,
+           "dispatch_stage_s_per_GB.dtn": 0.25, "dispatch_put_s_per_GB.dtn": 0.5,
+           "dispatch_wait_s_per_GB.dtn": 0.5, "dispatch_unpad_s_per_GB.dtn": 0.25,
+           "verify_lag_p50_s.dtn": 4.75}
+DISPATCHES = "digest_dispatches_total"
+
+
+def _obs(records, device_bytes=GB, counters=None):
+    return harness.Observations(t0=0.0, t1=10.0, device_bytes=device_bytes, device=None,
+                                peaks=None, records=records, counters=counters or {})
+
+
+@pytest.mark.parametrize("name", list(READERS))
+def test_verify_path_reader_on_synthetic_records(name):
+    read = harness.load_reader(name)
+    assert read(_obs(_records())) == pytest.approx(READERS[name])
+    assert read(_obs(_records())) == verify_split.split(
+        _records(), 0.0, 10.0, GB)[name.removesuffix(".dtn")]
+    # nothing to read: no spans at all, or (a rate per GB) no device bytes
+    assert read(_obs([])) is None
+    if name.endswith("_per_GB.dtn"):
+        assert read(_obs(_records(), device_bytes=0)) is None
+
+
+def test_view_dispatch_share_on_synthetic_counters():
+    read = harness.load_reader("view_dispatch_share.dtn")
+    view, staged = (DISPATCHES, "view"), (DISPATCHES, "staged")
+    assert read(_obs([], counters={view: 3.0, staged: 1.0})) == pytest.approx(75.0)
+    assert read(_obs([], counters={view: 5.0})) == pytest.approx(100.0)
+    assert read(_obs([], counters={staged: 2.0})) == 0.0
+    # no dispatch in the window: nothing to read
+    assert read(_obs([], counters={view: 0.0, staged: 0.0})) is None
+    assert read(_obs(_records())) is None
+
+
 def test_a_traced_run_on_the_cpu_reports_every_number(tmp_path):
     cell = harness.resolve_cell("bigfile.closed1")
     cfg = copy.deepcopy(cell.config)
@@ -104,6 +141,10 @@ def test_a_traced_run_on_the_cpu_reports_every_number(tmp_path):
     pair = res["digest_wait_vs_annotation"]
     assert pair["annotations"] >= pair["spans"] >= 1
     assert pair["open_max_abs_us"] < 5e3 and pair["close_max_abs_us"] < 5e3
+    # the per-layer readers give the split's numbers, on the same run
+    for name in READERS:
+        assert out["metrics"][name]["value"] == res[name.removesuffix(".dtn")], name
+    assert 0 <= out["metrics"]["view_dispatch_share.dtn"]["value"] <= 100
     # the harness is left as it was
     assert harness.load_adapter.__module__ == "bench.harness"
     assert verify_split.devtrace.extract.__module__ == "bench.devtrace"

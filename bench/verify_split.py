@@ -21,7 +21,9 @@ the bytes the integrity engine handed to the device in it.
                                seconds: how much of the engine's work the
                                spans inside it account for
 
-Each is ``None`` where its spans or the device bytes are missing. With
+Each is ``None`` where its spans or the device bytes are missing. The
+verify path's per-layer readers (``bench/metrics/<name>.dtn.py``) return
+the same numbers from a run's ``Observations`` (``read``). With
 ``--trace 1`` it adds how far each ``digest_wait`` span starts from its
 ``jax.profiler.TraceAnnotation`` twin in the profiler's host plane, placed
 on the host clock by the window marker: near the window's open and its
@@ -34,7 +36,6 @@ import os
 import statistics
 import sys
 import time
-import types
 
 if __name__ == "__main__":
     _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -146,53 +147,38 @@ def offsets(records, starts_ns: list[float], marker_ns: float, marker_s: float,
     return out
 
 
-def records_of(tracer) -> list[tuple]:
-    """A tracer's spans as ``(cat, name, t0, t1, args)``."""
-    return [(s.cat, s.name, s.t0, s.t1, dict(s.args, task=s.task, lane=s.lane))
-            for s in tracer.spans()]
+def read(obs, name: str) -> float | None:
+    """The number ``name`` of ``split`` for a window's ``Observations``: what
+    each verify-path reader of ``bench/metrics`` returns."""
+    return split(obs.records, obs.t0, obs.t1, obs.device_bytes)[name]
 
 
 def run(cell: harness.Cell, seed: int, seconds: float, trace: bool, **kw) -> tuple[dict, dict]:
-    """``harness.run_cell`` with the service's spans, the window and (traced)
-    the annotation starts kept; returns its result and the split."""
+    """``harness.run_cell`` with its observations and (traced) the annotation
+    starts kept; returns its result and the split."""
     cap: dict = {}
-    real = (harness.load_adapter, devtrace.extract, devtrace.reduce)
-
-    def load_adapter(name: str):
-        base = real[0](name).Deployment
-
-        class Recording(base):
-            def window(self, at_close, t0, t_close, log):
-                out = super().window(at_close, t0, t_close, log)
-                cap.update(t0=t0, t1=t_close, device_bytes=out["device_bytes"])
-                return out
-
-            def stop(self):
-                if self.svc is not None:
-                    cap["records"] = records_of(self.svc.tracer)
-                return super().stop()
-
-        return types.SimpleNamespace(Deployment=Recording)
+    real = (devtrace.extract, devtrace.reduce)
 
     def extract(logdir: str) -> dict:
-        out = real[1](logdir)
+        out = real[0](logdir)
         cap["annotations"] = annotations(logdir, "digest_wait")
         return out
 
     def reduce(tr: dict, marker_s: float, *a, **k):
         cap["marker"] = (tr["marker_ns"], marker_s)
-        return real[2](tr, marker_s, *a, **k)
+        return real[1](tr, marker_s, *a, **k)
 
-    harness.load_adapter, devtrace.extract, devtrace.reduce = load_adapter, extract, reduce
+    devtrace.extract, devtrace.reduce = extract, reduce
     try:
-        out = harness.run_cell(cell, seed, seconds, trace, **kw)
+        out = harness.run_cell(cell, seed, seconds, trace,
+                               observe=lambda obs: cap.update(obs=obs), **kw)
     finally:
-        harness.load_adapter, devtrace.extract, devtrace.reduce = real
-    records = cap.get("records", [])
-    res = split(records, cap["t0"], cap["t1"], cap["device_bytes"])
+        devtrace.extract, devtrace.reduce = real
+    obs = cap["obs"]
+    res = split(obs.records, obs.t0, obs.t1, obs.device_bytes)
     if "annotations" in cap:
         res["digest_wait_vs_annotation"] = offsets(
-            records, cap["annotations"], *cap["marker"], cap["t0"], cap["t1"])
+            obs.records, cap["annotations"], *cap["marker"], obs.t0, obs.t1)
     return out, res
 
 
